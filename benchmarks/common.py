@@ -52,21 +52,37 @@ def time_fn(fn, *args, iters: int = 3, warmup: int = 1,
     ``LAST_STATS`` for the next ``emit`` to attach to its row (schema-2
     --json payloads)."""
     global LAST_STATS
+    [(median, LAST_STATS)] = time_interleaved(
+        [(fn, args)], iters=iters, warmup=warmup, min_iters=min_iters)
+    return median
+
+
+def time_interleaved(calls, *, iters: int = 3, warmup: int = 1,
+                     min_iters: int = 1) -> list:
+    """``time_fn`` over several ``(fn, args)`` calls at once, one call of
+    each per round, so a burst of host load lands on every call alike —
+    what a comparison between them needs.  Returns ``(median seconds,
+    stats)`` per call, ``stats`` in ``LAST_STATS``'s form."""
     if SMOKE:
         iters, warmup = max(1, min_iters), min(warmup, 1)
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    times = []
+    for fn, args in calls:
+        for _ in range(warmup):
+            jax.block_until_ready(fn(*args))
+    times = [[] for _ in calls]
     for _ in range(iters):
-        t0 = obs.monotonic()
-        jax.block_until_ready(fn(*args))
-        times.append(obs.monotonic() - t0)
-    times.sort()
-    LAST_STATS = {"iters": len(times),
-                  "p10_us": round(_percentile(times, 0.1) * 1e6, 3),
-                  "p50_us": round(_percentile(times, 0.5) * 1e6, 3),
-                  "p90_us": round(_percentile(times, 0.9) * 1e6, 3)}
-    return times[len(times) // 2]
+        for (fn, args), ts in zip(calls, times):
+            t0 = obs.monotonic()
+            jax.block_until_ready(fn(*args))
+            ts.append(obs.monotonic() - t0)
+    out = []
+    for ts in times:
+        ts.sort()
+        out.append((ts[len(ts) // 2],
+                    {"iters": len(ts),
+                     "p10_us": round(_percentile(ts, 0.1) * 1e6, 3),
+                     "p50_us": round(_percentile(ts, 0.5) * 1e6, 3),
+                     "p90_us": round(_percentile(ts, 0.9) * 1e6, 3)}))
+    return out
 
 
 def make_gspn_inputs(batch: int, channels: int, h: int, w: int,

@@ -65,16 +65,25 @@ def run():
     resolutions = RESOLUTIONS[:1] if common.SMOKE else RESOLUTIONS
     for h, w in resolutions:
         inputs32 = make_gspn_inputs(B, CP, h, w)
+        by_dtype = {dname: tuple(a.astype(dtype) for a in inputs32)
+                    for dname, dtype in DTYPES}
+        fwds = {impl: jax.jit(lambda *a, impl=impl: gspn_scan(*a, impl=impl))
+                for impl in IMPLS}
+        # The pallas fwd rungs feed the gate's STRICT bf16<f32 ordering
+        # check: time them interleaved (a load burst lands on both
+        # dtypes) and keep a median-of-5 even under --smoke.
+        gated = dict(zip(by_dtype, common.time_interleaved(
+            [(fwds["pallas"], by_dtype[d]) for d in by_dtype],
+            iters=5, min_iters=5)))
         ref = None
         for dname, dtype in DTYPES:
-            inputs = tuple(a.astype(dtype) for a in inputs32)
+            inputs = by_dtype[dname]
             for impl in IMPLS:
-                fwd = jax.jit(lambda *a, impl=impl: gspn_scan(*a, impl=impl))
-                # The pallas fwd rungs feed the gate's STRICT bf16<f32
-                # ordering check — keep a median-of-5 even under --smoke
-                # so one scheduler hiccup cannot flip the comparison.
-                t_f = time_fn(fwd, *inputs, iters=5,
-                              min_iters=5 if impl == "pallas" else 1)
+                fwd = fwds[impl]
+                if impl == "pallas":
+                    t_f, common.LAST_STATS = gated[dname]
+                else:
+                    t_f = time_fn(fwd, *inputs, iters=5)
                 out = np.asarray(fwd(*inputs), np.float32)
                 if dname == "f32" and impl == "xla":
                     ref = out

@@ -18,11 +18,11 @@ launch counts of the Pallas path by counting ``pallas_call`` invocations:
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from benchmarks.common import emit, time_fn
 from repro.core import gspn as G
 from repro.kernels import gspn_multidir as MK
+from repro.kernels import gspn_scan as GS
 from repro.kernels.ops import gspn_scan
 
 # Square so the quad-batched rung applies (CPU-scaled).
@@ -72,18 +72,20 @@ def _quad_batched(x, wl, wc, wr, lam):
 
 
 def _count_pallas_launches(fn):
+    """Kernel launches ``fn`` issues, counted at the repo's one launch
+    funnel (``gspn_scan.pallas_call``)."""
     n = [0]
-    real = pl.pallas_call
+    real = GS.pallas_call
 
     def wrap(*a, **k):
         n[0] += 1
         return real(*a, **k)
 
-    pl.pallas_call = wrap
+    GS.pallas_call = wrap
     try:
         jax.block_until_ready(fn())
     finally:
-        pl.pallas_call = real
+        GS.pallas_call = real
     return n[0]
 
 

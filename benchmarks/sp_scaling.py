@@ -21,17 +21,18 @@ exchange ahead of the local scan: exchange fully exposed) and ``skip``
     hidden  = serial - overlap       # how much of it overlap recovers
     overlap_efficiency = hidden / exposed
 
-A 2-host rung repeats the measurement on a true multi-PROCESS mesh (two
-coordinated children through ``repro.compat.distributed_initialize``,
-gloo CPU collectives) and is skipped with a stderr note where the
-runtime lacks multiprocess support.
-
-Device counts are forced with ``--xla_force_host_platform_device_count``,
-which must be set BEFORE jax imports, so each rung runs in a child
-interpreter (``python -m benchmarks.sp_scaling --devices N``); the parent
-``run()`` re-emits the children's CSV rows.  CPU timings are indicative
-only (like fig3, the ladder is reproduced structurally); the traffic
-model is exact.
+On a TPU host the whole ladder runs in this one process over the
+chips it holds (1, 2, 4 devices of the host): a chip belongs to one
+process, so no child may need it.  On the CPU, device counts are forced
+with ``--xla_force_host_platform_device_count``, which must be set
+BEFORE jax imports, so each rung runs in a child interpreter (``python
+-m benchmarks.sp_scaling --devices N``) and the parent ``run()``
+re-emits the children's CSV rows; a 2-host rung then repeats the
+measurement on a true multi-PROCESS mesh (two coordinated children
+through ``repro.compat.distributed_initialize``, gloo CPU collectives).
+A rung that fails fails the ladder.  CPU timings are indicative only
+(like fig3, the ladder is reproduced structurally); the traffic model
+is exact.
 """
 
 from __future__ import annotations
@@ -135,11 +136,17 @@ def _child(n_dev: int, smoke: bool) -> None:
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n_dev}")
+    import benchmarks.common as common
+    common.SMOKE = smoke
+    _rung(n_dev, smoke)
+
+
+def _rung(n_dev: int, smoke: bool) -> None:
+    """One device count's rows, on the first ``n_dev`` devices of this
+    process."""
     import jax
     import jax.numpy as jnp
 
-    import benchmarks.common as common
-    common.SMOKE = smoke
     from benchmarks.common import emit, time_fn, make_gspn_inputs
     from repro.launch.mesh import make_sp_mesh
     from repro.parallel.gspn_sp import gspn_scan_sp
@@ -214,12 +221,9 @@ def _free_port() -> int:
 
 
 def _run_multihost(smoke: bool):
-    """Launch the coordinated 2-process overlap rung; yield proc-0 rows.
-
-    Multiprocess CPU collectives need a working gloo transport — where
-    the runtime lacks it the rung is skipped with a stderr note rather
-    than failing the whole ladder.
-    """
+    """Launch the coordinated 2-process overlap rung; return proc-0 rows.
+    A child that fails (or a runtime without multiprocess CPU
+    collectives) fails the ladder."""
     port = _free_port()
     procs = []
     for i in range(MULTIHOST_PROCS):
@@ -237,20 +241,26 @@ def _run_multihost(smoke: bool):
             p.kill()
             outs.append(p.communicate())
     if any(p.returncode != 0 for p in procs):
-        err = " | ".join(o[1].strip().splitlines()[-1] if o[1].strip()
-                         else f"rc={p.returncode}"
-                         for p, o in zip(procs, outs))
-        print(f"sp_scaling: multihost rung skipped ({err})",
-              file=sys.stderr, flush=True)
-        return []
+        err = "\n".join(f"--- process {i} (rc={p.returncode}):\n{o[1]}"
+                        for i, (p, o) in enumerate(zip(procs, outs)))
+        raise RuntimeError(f"sp_scaling multihost rung failed:\n{err}")
     return [ln for ln in outs[0][0].splitlines()
             if ln.startswith("sp_scaling/")]
 
 
 def run() -> None:
+    import jax
+
     import benchmarks.common as common
 
     devices = SMOKE_DEVICES if common.SMOKE else DEVICES
+    if jax.default_backend() == "tpu":
+        # One process holds the chips: rungs run here, over the devices
+        # this host has (emit() already records and prints each row).
+        for n_dev in devices:
+            if n_dev <= len(jax.devices()):
+                _rung(n_dev, common.SMOKE)
+        return
     for n_dev in devices:
         cmd = [sys.executable, "-m", "benchmarks.sp_scaling",
                "--devices", str(n_dev)]

@@ -1,66 +1,38 @@
-"""Version-compatibility shims for jax APIs that moved between releases.
+"""Thin wrappers over the jax parallelism APIs this repo uses.
 
-The production target is current jax (``jax.shard_map``, mesh axis types,
-``jax.set_mesh``); CI containers may pin older releases (0.4.x) where the
-same functionality lives under different names.  Everything
-parallelism-related in this repo goes through these four helpers so the
-kernels and collectives run unchanged on both.
+The repo targets one jax release (pinned in ``requirements-ci.txt``);
+these helpers fix the options every call site wants — all-Auto mesh
+axes, unchecked-replication ``shard_map``, the gloo CPU transport — so
+parallelism code states them once.
 """
 
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5: explicit/auto axis types on meshes
-    _AXIS_TYPE = jax.sharding.AxisType
-except AttributeError:  # 0.4.x: meshes are untyped (all-auto)
-    _AXIS_TYPE = None
-
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with all-Auto axis types where the API has them."""
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(_AXIS_TYPE.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with all-Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Unchecked-replication shard_map on both current and 0.4.x jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """Unchecked-replication ``jax.shard_map``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on current jax; on 0.4.x the legacy ``Mesh`` object is
-    itself the context manager (NamedSharding-based code carries its mesh
-    explicitly there, so the context is only needed for API parity).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def ambient_mesh():
     """The mesh installed by :func:`set_mesh`, or None when unset."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        return None if m.empty else m
-    except AttributeError:
-        pass
-    try:  # 0.4.x legacy global mesh context
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # noqa: BLE001
-        return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def distributed_initialize(coordinator_address: str, num_processes: int,
@@ -70,10 +42,11 @@ def distributed_initialize(coordinator_address: str, num_processes: int,
     ``jax.distributed.initialize`` alone is not enough on the CPU
     backend: without a CPU collectives implementation every cross-process
     computation fails with "Multiprocess computations aren't implemented
-    on the CPU backend".  This shim selects the gloo transport first
-    (where the knob exists — jax >= 0.4.34; real accelerator backends
-    ignore it) and then initializes the distributed runtime, so the same
-    launch code drives a CPU test fleet and a TPU pod.
+    on the CPU backend".  This selects the gloo transport first (real
+    accelerator backends ignore it) and then initializes the distributed
+    runtime, so the same launch code drives a CPU test fleet and a TPU
+    pod.  The coordinator, process count and id are always explicit: a
+    machine without a metadata server cannot look them up.
 
     Must run BEFORE any jax computation; per-process device counts (e.g.
     ``--xla_force_host_platform_device_count``) must already be in
@@ -81,10 +54,7 @@ def distributed_initialize(coordinator_address: str, num_processes: int,
     callers treating multi-process support as optional should catch and
     skip.
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # knob absent: rely on backend
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

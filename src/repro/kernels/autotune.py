@@ -70,7 +70,7 @@ ENUM_CAP = 512
 
 # Per-direction kernel geometry: streamed operand count and VMEM carry
 # rows (the adjoint kernels hold three tap·adjoint rows, always f32 —
-# see gspn_scan._bwd_kernel / gspn_multidir._bwd_pair_kernel).
+# see gspn_scan._bwd_step).
 DIRECTIONS = ("fwd", "bwd", "pair_fwd", "pair_bwd", "quad")
 _N_STREAMS = {"fwd": 6, "bwd": 5, "pair_fwd": 6, "pair_bwd": 5, "quad": 6}
 _CARRY_ROWS = {"fwd": 1, "bwd": 3, "pair_fwd": 1, "pair_bwd": 3, "quad": 1}
@@ -124,10 +124,14 @@ def plans_summary() -> str:
 
 
 @functools.lru_cache(maxsize=4)
-def device_kind(interpret: bool = False) -> str:
-    """Normalised device cache key ('TPU v5e' → 'tpu-v5e').  Interpret-mode
-    runs (the CPU validation path) get their own namespace so interpreter
-    timings can never leak onto real silicon, and vice versa."""
+def device_kind(interpret: bool | None = None) -> str:
+    """Normalised device cache key ('TPU v5 lite' → 'tpu-v5-lite').
+    Interpret-mode runs (the CPU validation path) get their own namespace
+    so interpreter timings can never leak onto real silicon, and vice
+    versa.  ``None`` is a launch whose interpret mode follows the platform
+    (``gspn_scan.pallas_call``): interpreted everywhere but on a TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     kind = jax.devices()[0].device_kind.lower().replace(" ", "-")
     return f"{kind}+interpret" if interpret else kind
 
@@ -189,14 +193,13 @@ class ScanKey:
 class Candidate:
     """One tunable layout.  ``row_tile`` is the tile knob that reaches the
     kernel (rows per sequential grid step — the grid split is ``h //
-    row_tile``); ``double_buffer`` is the admission layout: True reserves
-    prefetch headroom for pipelining (the safe default), False admits
-    larger tiles that fit only single-buffered (the aggressive layout the
-    measurement decides on).  ``pipeline_depth`` selects the kernel
-    structure itself: 1 = the revolving-buffer BlockSpec stream (the
-    pre-PR6 kernels, bit-for-bit), 2 = the explicitly staged pipeline
-    (DESIGN.md §12: bulk widen-on-load input stages + f32 out-stage with
-    one downcast writeback per tile)."""
+    row_tile``); ``double_buffer`` is the accounting layout: True counts
+    the prefetch copy Pallas keeps of every block (the footprint the
+    compiler allocates, and the only one the enumerator admits); False
+    is the resident-only footprint, kept for reporting.
+    ``pipeline_depth`` selects the kernel structure itself: 1 = one plane
+    per grid step, row by row; 2 = all planes per grid step, staged in
+    f32 ``(T, G, W)`` layout (DESIGN.md §12)."""
     row_tile: int
     double_buffer: bool = True
     pipeline_depth: int = 1
@@ -206,7 +209,7 @@ class Candidate:
             self.row_tile, key.w, key.stream_bytes, key.n_streams,
             double_buffer=self.double_buffer,
             carry_dtype_bytes=key.carry_bytes,
-            pipeline_depth=self.pipeline_depth)
+            pipeline_depth=self.pipeline_depth, planes=max(key.c, 1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,37 +228,40 @@ def depth_admissible(key: ScanKey, pipeline_depth: int) -> bool:
     return pipeline_depth == 2 and key.stream_bytes < 4
 
 
-def heuristic_pipeline_depth(key: ScanKey) -> int:
+def heuristic_pipeline_depth(key: ScanKey, *,
+                             row_tile: int | None = None) -> int:
     """Static-fallback depth: the staged pipeline for narrow streams
-    (bf16/fp8), the classic stream for full-width f32."""
-    return 2 if key.stream_bytes < 4 else 1
+    (bf16/fp8) when its tile (``row_tile``, else the smallest admissible
+    one) fits the VMEM budget with all G planes resident; otherwise the
+    classic one-plane-per-step stream, which fits at any G and is what
+    full-width f32 always runs."""
+    if not depth_admissible(key, 2):
+        return 1
+    t = row_tile or tuning.admissible_tiles(key.h, key.stream_bytes,
+                                            DEFAULT_CAP)[0]
+    fits = Candidate(t, pipeline_depth=2).working_set(key) \
+        <= tuning.VMEM_BYTES
+    return 2 if fits else 1
 
 
 def enumerate_candidates(key: ScanKey, *,
                          vmem_budget: int = tuning.VMEM_BYTES,
                          cap: int = ENUM_CAP) -> list[Candidate]:
     """All configs the tuner may time (and therefore emit) for ``key``:
-    power-of-two divisors of the scan length whose working set fits the
-    VMEM budget — double-buffered where possible, single-buffered as the
-    aggressive extension — at every admissible pipeline depth (depth 2
-    only for narrow streams).  Deduplicated on ``(row_tile,
-    pipeline_depth)`` (the knobs that reach the kernel), keeping the
-    double-buffered admission label."""
+    the admissible row tiles of the scan length (``tuning.
+    tile_admissible``: power-of-two multiples of the dtype's sublane tile,
+    or the whole length) whose double-buffered working set fits the VMEM
+    budget — Pallas always double-buffers its blocks, so a tile that fits
+    only single-buffered is refused by the compiler — at every admissible
+    pipeline depth (depth 2 only for narrow streams)."""
     out: list[Candidate] = []
-    seen: set[tuple[int, int]] = set()
-    t = 1
-    while t <= cap and key.h % t == 0:
+    for t in tuning.admissible_tiles(key.h, key.stream_bytes, cap):
         for depth in PIPELINE_DEPTHS:
             if not depth_admissible(key, depth):
                 continue
-            for db in (True, False):
-                cand = Candidate(row_tile=t, double_buffer=db,
-                                 pipeline_depth=depth)
-                if (t, depth) not in seen \
-                        and cand.working_set(key) <= vmem_budget:
-                    seen.add((t, depth))
-                    out.append(cand)
-        t *= 2
+            cand = Candidate(row_tile=t, pipeline_depth=depth)
+            if cand.working_set(key) <= vmem_budget:
+                out.append(cand)
     return out
 
 
@@ -271,7 +277,7 @@ def heuristic_row_tile(key: ScanKey, *, cap: int = DEFAULT_CAP,
     return tuning.pick_row_tile(
         key.h, key.w, key.stream_bytes, vmem_budget=vmem_budget, cap=cap,
         n_streams=key.n_streams, carry_dtype_bytes=key.carry_bytes,
-        pipeline_depth=depth).row_tile
+        pipeline_depth=depth, planes=max(key.c, 1)).row_tile
 
 
 # ---------------------------------------------------------------------------
@@ -374,25 +380,26 @@ def _entry_depth(entry: dict) -> int:
 def _entry_invalid_reason(key: ScanKey, entry: dict, *,
                           vmem_budget: int = tuning.VMEM_BYTES) -> str | None:
     """Why a cache entry cannot be honoured for this key, or ``None`` when
-    it is valid: the row tile must be a power of two dividing H, the
-    pipeline depth known, and the minimal (single-buffered) working set at
-    that depth must fit the budget.  ``plan_for`` turns a non-None reason
+    it is valid: the row tile must be admissible for H (a power-of-two
+    multiple of the dtype's sublane tile dividing H, or H itself —
+    ``tuning.tile_admissible``), the pipeline depth known, and the
+    double-buffered working set at that depth (what the compiler
+    allocates) must fit the budget.  ``plan_for`` turns a non-None reason
     into an obs counter + event so a corrupted or stale cache is visible
     instead of silently degrading to the heuristic."""
     try:
         t = int(entry["row_tile"])
     except (KeyError, TypeError, ValueError):
         return f"row_tile missing or non-integer: {entry.get('row_tile')!r}"
-    if t < 1 or (t & (t - 1)):
-        return f"row_tile {t} is not a positive power of two"
-    if key.h % t:
-        return f"row_tile {t} does not divide h={key.h}"
+    if not tuning.tile_admissible(t, key.h, key.stream_bytes):
+        return (f"row_tile {t} is not admissible for h={key.h}: it must "
+                f"divide h and be h or a power of two multiple of "
+                f"{tuning.sublane_rows(key.stream_bytes)} rows")
     depth = _entry_depth(entry)
     if depth not in PIPELINE_DEPTHS:
         return (f"pipeline_depth {entry.get('pipeline_depth')!r} not in "
                 f"{PIPELINE_DEPTHS}")
-    ws = Candidate(t, double_buffer=False,
-                   pipeline_depth=depth).working_set(key)
+    ws = Candidate(t, pipeline_depth=depth).working_set(key)
     if ws > vmem_budget:
         return f"working set {ws}B exceeds VMEM budget {vmem_budget}B"
     return None
@@ -425,8 +432,8 @@ def plan_for_spec(spec: ScanSpec, h: int, w: int, *, c: int = 0,
                   str(jnp.dtype(spec.carry_dtype)),
                   spec.channel_shared, spec.boundary)
     if spec.row_tile is not None:
-        depth = (heuristic_pipeline_depth(key) if spec.pipeline_depth is None
-                 else spec.pipeline_depth)
+        depth = (heuristic_pipeline_depth(key, row_tile=spec.row_tile)
+                 if spec.pipeline_depth is None else spec.pipeline_depth)
         plan = ScanPlan(spec.row_tile, depth)
         _record_plan(key, plan, "explicit")
         return plan
@@ -553,7 +560,7 @@ def _make_operands(key: ScanKey, seed: int = 0):
     return tuple(map(cast, (x, wl, wc, wr, lam))), g // gw
 
 
-def default_runner_factory(key: ScanKey, *, interpret: bool = True,
+def default_runner_factory(key: ScanKey, *, interpret: bool | None = None,
                            seed: int = 0):
     """Builds, per candidate, a zero-arg jitted launch of the ACTUAL
     kernel the key describes (lazy kernel imports — this module is
@@ -603,7 +610,7 @@ def default_runner_factory(key: ScanKey, *, interpret: bool = True,
 def autotune_key(key: ScanKey, *, candidates=None, iters: int = 3,
                  warmup: int = 1, cache: TuningCache | None = None,
                  timer=None, runner_factory=None,
-                 interpret: bool = True) -> dict:
+                 interpret: bool | None = None) -> dict:
     """Time every candidate for ``key`` and cache the winner.
 
     The candidate list always contains the heuristic's choice (the
@@ -678,7 +685,8 @@ WARM_SPECS = [
 
 
 def warm(specs=None, *, cache: TuningCache | None = None, iters: int = 2,
-         warmup: int = 1, interpret: bool = True, verbose: bool = True):
+         warmup: int = 1, interpret: bool | None = None,
+         verbose: bool = True):
     """Tune every spec on the current device and return the cache."""
     cache = cache if cache is not None else get_cache()
     for h, w, c, direction, impl, dtype, cs in (specs or WARM_SPECS):

@@ -27,8 +27,9 @@ H×W — see ``repro.core.gspn.directional_scan`` — or one for square grids.
 
 Direction handling is pure index arithmetic: for the reverse member of a
 pair the H tiles are visited in reverse (index_map) and rows within a tile
-iterate backwards (in-kernel ``r_eff``).  No flipped copies of any operand
-exist in either the forward or the adjoint pass.
+iterate backwards (in the kernel's row walk).  No flipped copies of any operand
+exist in either the forward or the adjoint pass.  Every launch here is
+the shared kernel of ``gspn_scan.launch_scan`` with a direction grid axis.
 
 Layout: x (G, H, W); taps/lam stacked per direction (2, G_w, H, W) /
 (2, G, H, W).  Output (2, G, H, W): out[0] = top→bottom scan, out[1] =
@@ -37,30 +38,16 @@ bottom→top scan (both in the UNFLIPPED layout of x).
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro import obs
 from repro.kernels import autotune
-from repro.kernels.gspn_scan import (CompilerParams, _dir_scan, _masked_shifts,
-                                     _row, _shift_left, _shift_right,
-                                     _stage_rows)
+from repro.kernels.gspn_scan import launch_scan
 from repro.kernels.spec import ScanSpec
-
-
-def _launch_span(name, plan, dtype, g, h, w):
-    """Traced-launch span for the fused kernels (DESIGN.md §13): fires
-    once per jit trace, annotated with the tuner-resolved plan."""
-    return obs.trace("kernel.launch", kernel=name, row_tile=plan.row_tile,
-                     pipeline_depth=plan.pipeline_depth,
-                     dtype=str(jnp.dtype(dtype)), g=g, h=h, w=w)
 
 
 def _pair_spec(spec: ScanSpec | None, direction: str, dtype, *,
                channels_per_weight: int = 1, carry_dtype=jnp.float32,
-               interpret: bool = True, row_tile: int | None = None,
+               interpret: bool | None = None, row_tile: int | None = None,
                pipeline_depth: int | None = None) -> ScanSpec:
     """Build (from legacy kwargs) or normalise the spec of one fused
     pair/quad launch: these entry points own the ``multidir`` impl leg,
@@ -77,95 +64,22 @@ def _pair_spec(spec: ScanSpec | None, direction: str, dtype, *,
     return spec.with_(**changes)
 
 
-def _pair_plan(spec: ScanSpec, h: int, w: int, c: int) -> "autotune.ScanPlan":
-    """Tile + pipeline depth for the fused pair/quad kernels: measured
-    cache entry when the tuner knows this spec's canonical key at this
-    (device, shape), VMEM-heuristic fallback otherwise (DESIGN.md
-    §11/§12/§14).  The fallback shares the single-direction kernels' cap
-    so fused/unfused tile identically on a cache miss."""
-    return autotune.plan_for_spec(spec, h, w, c=c)
+def _stacked(a):
+    """(D, P, H, W) -> (D·P, H, W): the kernels take 3-D blocks only (a
+    4-D block's lane slice must be 128-aligned, which W < 128 is not)."""
+    return a.reshape((-1,) + a.shape[2:])
 
 
 # ---------------------------------------------------------------------------
-# Forward pair kernel.
+# Fused opposite-direction pair: direction 0 walks top→bottom, direction 1
+# bottom→top; x is SHARED — both directions read the same tiles in
+# opposite order.
 # ---------------------------------------------------------------------------
-
-def _kernel(row_tile,
-            x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref, carry_ref):
-    d = pl.program_id(0)
-    ti = pl.program_id(2)
-
-    @pl.when(ti == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
-
-    def body(r, h_prev):
-        # T->B walks rows forward; B->T walks them backward.
-        r_eff = jnp.where(d % 2 == 0, r, row_tile - 1 - r)
-        h_new = (
-            _row(wl_ref, r_eff) * _shift_right(h_prev)
-            + _row(wc_ref, r_eff) * h_prev
-            + _row(wr_ref, r_eff) * _shift_left(h_prev)
-            + _row(lam_ref, r_eff) * _row(x_ref, r_eff)
-        )
-        o_ref[0, pl.dslice(r_eff, 1), :] = h_new.astype(o_ref.dtype)
-        return h_new
-
-    # f32 row recurrence; cross-tile carry stored in the scratch's dtype.
-    carry_ref[...] = jax.lax.fori_loop(
-        0, row_tile, body,
-        carry_ref[...].astype(jnp.float32)).astype(carry_ref.dtype)
-
-
-def _kernel_staged(row_tile, cpw,
-                   x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref,
-                   carry_ref):
-    """Depth-2 pair/quad forward kernel: all planes of one direction per
-    grid step, staged streams (DESIGN.md §12).  The refs arrive with the
-    direction axis already peeled (``.at[0]``); same f32 recurrence and
-    operation order as ``_kernel`` vectorised over the plane axis.  The
-    sequential loop is a ref-free ``_dir_scan`` whose row direction
-    follows the grid's direction axis — no staged data is ever flipped
-    (identical values row for row to the legacy ``r_eff`` walk)."""
-    del row_tile
-    d = pl.program_id(0)
-    ti = pl.program_id(1)
-
-    @pl.when(ti == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
-
-    xs = _stage_rows(x_ref)                         # (T, G, W) f32
-    lams = _stage_rows(lam_ref)
-    wls = _stage_rows(wl_ref, cpw)
-    wcs = _stage_rows(wc_ref, cpw)
-    wrs = _stage_rows(wr_ref, cpw)
-    sr, sl = _masked_shifts(xs.shape[1:])
-
-    # lam*x stays inside the step — see the parity note in
-    # gspn_scan._fwd_kernel_staged (FMA contraction vs depth 1).
-    def step(h_prev, row):
-        x_r, wl_r, wc_r, wr_r, lam_r = row
-        h_new = (
-            wl_r * sr(h_prev)
-            + wc_r * h_prev
-            + wr_r * sl(h_prev)
-            + lam_r * x_r
-        )
-        return h_new, h_new
-
-    h0 = carry_ref[...].astype(jnp.float32)[:, 0, :]         # (G, W)
-    # T->B walks rows forward; B->T walks them backward.
-    h_last, ys = _dir_scan(step, h0, (xs, wls, wcs, wrs, lams),
-                           d % 2 != 0)
-    carry_ref[...] = h_last[:, None, :].astype(carry_ref.dtype)
-    o_ref[...] = jnp.swapaxes(ys, 0, 1).astype(o_ref.dtype)
-
 
 def gspn_scan_bidir_pallas(x, taps, lam2, *, spec: ScanSpec | None = None,
                            channels_per_weight: int = 1,
                            row_tile: int | None = None,
-                           interpret: bool = True,
+                           interpret: bool | None = None,
                            carry_dtype=jnp.float32,
                            pipeline_depth: int | None = None):
     """x: (G, H, W); taps: dict with wl/wc/wr each (2, G_w, H, W);
@@ -179,240 +93,46 @@ def gspn_scan_bidir_pallas(x, taps, lam2, *, spec: ScanSpec | None = None,
                       channels_per_weight=channels_per_weight,
                       carry_dtype=carry_dtype, interpret=interpret,
                       row_tile=row_tile, pipeline_depth=pipeline_depth)
-    cpw = spec.channels_per_weight
-    gw = g // cpw
-    carry_dtype = jnp.dtype(spec.carry_dtype)
-    interpret = spec.interpret
-    plan = _pair_plan(spec, h, w, g)
-    row_tile, pipeline_depth = plan.row_tile, plan.pipeline_depth
-    assert h % row_tile == 0
-    assert pipeline_depth in (1, 2), pipeline_depth
-    n_tiles = h // row_tile
-
-    def ti_eff(d, ti):
-        return jnp.where(d == 0, ti, n_tiles - 1 - ti)
-
-    if pipeline_depth == 1:
-        # x is SHARED: both directions read the same tiles (opposite order).
-        x_spec = pl.BlockSpec((1, row_tile, w),
-                              lambda d, gi, ti: (gi, ti_eff(d, ti), 0))
-        wt_spec = pl.BlockSpec(
-            (1, 1, row_tile, w),
-            lambda d, gi, ti: (d, gi // cpw, ti_eff(d, ti), 0))
-        lam_spec = pl.BlockSpec((1, 1, row_tile, w),
-                                lambda d, gi, ti: (d, gi, ti_eff(d, ti), 0))
-        out_spec = pl.BlockSpec((1, 1, row_tile, w),
-                                lambda d, gi, ti: (d, gi, ti_eff(d, ti), 0))
-
-        def kernel(x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref, carry_ref):
-            _kernel(row_tile, x_ref,
-                    wl_ref.at[0], wc_ref.at[0], wr_ref.at[0], lam_ref.at[0],
-                    o_ref.at[0], carry_ref)
-
-        call = pl.pallas_call(
-            kernel,
-            grid=(2, g, n_tiles),
-            in_specs=[x_spec, wt_spec, wt_spec, wt_spec, lam_spec],
-            out_specs=out_spec,
-            out_shape=jax.ShapeDtypeStruct((2, g, h, w), x.dtype),
-            scratch_shapes=[pltpu.VMEM((1, w), carry_dtype)],
-            compiler_params=CompilerParams(
-                dimension_semantics=("arbitrary",) * 3),
-            interpret=interpret,
-        )
-        with _launch_span("gspn_pair_fwd", plan, x.dtype, g, h, w):
-            return call(x, taps["wl"], taps["wc"], taps["wr"], lam2)
-
-    x_spec = pl.BlockSpec((g, row_tile, w),
-                          lambda d, ti: (0, ti_eff(d, ti), 0))
-    wt_spec = pl.BlockSpec((1, gw, row_tile, w),
-                           lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-    lam_spec = pl.BlockSpec((1, g, row_tile, w),
-                            lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-    out_spec = pl.BlockSpec((1, g, row_tile, w),
-                            lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-
-    def kernel(x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref, carry_ref):
-        _kernel_staged(row_tile, cpw, x_ref,
-                       wl_ref.at[0], wc_ref.at[0], wr_ref.at[0],
-                       lam_ref.at[0], o_ref.at[0], carry_ref)
-
-    call = pl.pallas_call(
-        kernel,
-        grid=(2, n_tiles),
-        in_specs=[x_spec, wt_spec, wt_spec, wt_spec, lam_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((2, g, h, w), x.dtype),
-        scratch_shapes=[pltpu.VMEM((g, 1, w), carry_dtype)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",) * 2),
-        interpret=interpret,
-    )
-    with _launch_span("gspn_pair_fwd", plan, x.dtype, g, h, w):
-        return call(x, taps["wl"], taps["wc"], taps["wr"], lam2)
-
-
-# ---------------------------------------------------------------------------
-# Adjoint pair kernel.
-#
-# The adjoint of the top→bottom scan walks rows from LAST to FIRST; the
-# adjoint of the bottom→top scan walks FIRST to LAST — so the fused adjoint
-# is the forward pair kernel's traversal with the direction roles swapped.
-# The carry holds the three tap*adjoint products of the previously
-# processed row:
-#     d=0:  g[i] = dy[i] + shift_left(wl[i+1]*g[i+1]) + wc[i+1]*g[i+1]
-#                        + shift_right(wr[i+1]*g[i+1])
-#     d=1:  same with i+1 -> i-1.
-# ---------------------------------------------------------------------------
-
-def _bwd_pair_kernel(row_tile,
-                     dy_ref, wl_ref, wc_ref, wr_ref, g_ref, carry_ref):
-    d = pl.program_id(0)
-    ti = pl.program_id(2)
-
-    @pl.when(ti == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
-
-    def body(r, _):
-        # Adjoint traversal is opposite to the forward one per direction.
-        r_eff = jnp.where(d == 0, row_tile - 1 - r, r)
-        g_row = (
-            _row(dy_ref, r_eff)
-            + _shift_left(carry_ref[0, :, :])
-            + carry_ref[1, :, :]
-            + _shift_right(carry_ref[2, :, :])
-        )
-        g_ref[0, pl.dslice(r_eff, 1), :] = g_row.astype(g_ref.dtype)
-        carry_ref[0, :, :] = _row(wl_ref, r_eff) * g_row
-        carry_ref[1, :, :] = _row(wc_ref, r_eff) * g_row
-        carry_ref[2, :, :] = _row(wr_ref, r_eff) * g_row
-        return 0
-
-    jax.lax.fori_loop(0, row_tile, body, 0)
-
-
-def _bwd_pair_kernel_staged(row_tile, cpw,
-                            dy_ref, wl_ref, wc_ref, wr_ref, g_ref,
-                            carry_ref):
-    """Depth-2 fused adjoint: all planes of one direction per grid step,
-    staged streams, three f32 tap·adjoint carry rows per plane riding the
-    ``_dir_scan`` carry.  Direction 0's adjoint walks rows last→first —
-    the scan's traced ``reverse`` flag, no staged data is flipped."""
-    del row_tile
-    d = pl.program_id(0)
-    ti = pl.program_id(1)
-
-    @pl.when(ti == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
-
-    dys = _stage_rows(dy_ref)                       # (T, G, W) f32
-    wls = _stage_rows(wl_ref, cpw)
-    wcs = _stage_rows(wc_ref, cpw)
-    wrs = _stage_rows(wr_ref, cpw)
-    sr, sl = _masked_shifts(dys.shape[1:])
-
-    def step(prods, row):
-        dy_r, wl_r, wc_r, wr_r = row
-        prod_l, prod_c, prod_r = prods
-        g_row = (
-            dy_r
-            + sl(prod_l)
-            + prod_c
-            + sr(prod_r)
-        )
-        return (wl_r * g_row, wc_r * g_row, wr_r * g_row), g_row
-
-    p0 = (carry_ref[0][:, 0, :], carry_ref[1][:, 0, :],
-          carry_ref[2][:, 0, :])
-    # Adjoint traversal is opposite to the forward one per direction.
-    prods, ys = _dir_scan(step, p0, (dys, wls, wcs, wrs), d == 0)
-    carry_ref[0], carry_ref[1], carry_ref[2] = \
-        (p[:, None, :] for p in prods)
-    g_ref[...] = jnp.swapaxes(ys, 0, 1).astype(g_ref.dtype)
+    gw = g // spec.channels_per_weight
+    plan = autotune.plan_for_spec(spec, h, w, c=g)
+    out = launch_scan(
+        spec, plan,
+        [(x, g), (_stacked(taps["wl"]), gw), (_stacked(taps["wc"]), gw),
+         (_stacked(taps["wr"]), gw), (_stacked(lam2), g)],
+        adjoint=False, n_dirs=2, reversed_dirs=(1,), out_dtype=x.dtype,
+        name="gspn_pair_fwd")
+    return out.reshape(2, g, h, w)
 
 
 def gspn_scan_bidir_bwd_pallas(dy2, wl2, wc2, wr2, *,
                                spec: ScanSpec | None = None,
                                channels_per_weight: int = 1,
                                row_tile: int | None = None,
-                               interpret: bool = True,
+                               interpret: bool | None = None,
                                pipeline_depth: int | None = None):
     """Fused adjoint of the pair scan.  dy2: (2, G, H, W); w*2:
-    (2, G_w, H, W), all in the UNFLIPPED layout.  Returns g2 = dL/dh
-    (pre-output-layer) as (2, G, H, W) f32 — one launch, no flipped
-    copies."""
+    (2, G_w, H, W), all in the UNFLIPPED layout.  The adjoint of the
+    top→bottom scan walks rows last→first, that of the bottom→top scan
+    first→last — the forward pair's traversal with the roles swapped.
+    Returns g2 = dL/dh (pre-output-layer) as (2, G, H, W) f32 — one
+    launch, no flipped copies."""
     _, g_dim, h, w = dy2.shape
-    # Streamed dtype is dy2's (bf16 tiles halve the working set); the
-    # adjoint carry is three f32 tap·adjoint rows regardless of policy
-    # (encoded by the "pair_bwd" direction leg — _pair_spec forces it).
+    # Streamed dtype is dy2's; the adjoint carry is three f32 tap·adjoint
+    # rows regardless of policy (encoded by the "pair_bwd" direction leg —
+    # _pair_spec forces it).
     spec = _pair_spec(spec, "pair_bwd", dy2.dtype,
                       channels_per_weight=channels_per_weight,
                       interpret=interpret, row_tile=row_tile,
                       pipeline_depth=pipeline_depth)
-    cpw = spec.channels_per_weight
-    gw = g_dim // cpw
-    interpret = spec.interpret
-    plan = _pair_plan(spec, h, w, g_dim)
-    row_tile, pipeline_depth = plan.row_tile, plan.pipeline_depth
-    assert h % row_tile == 0
-    assert pipeline_depth in (1, 2), pipeline_depth
-    n_tiles = h // row_tile
-
-    def ti_eff(d, ti):
-        # Opposite tile order to the forward pass, per direction.
-        return jnp.where(d == 0, n_tiles - 1 - ti, ti)
-
-    if pipeline_depth == 1:
-        wt_spec = pl.BlockSpec(
-            (1, 1, row_tile, w),
-            lambda d, gi, ti: (d, gi // cpw, ti_eff(d, ti), 0))
-        data_spec = pl.BlockSpec((1, 1, row_tile, w),
-                                 lambda d, gi, ti: (d, gi, ti_eff(d, ti), 0))
-
-        def kernel(dy_ref, wl_ref, wc_ref, wr_ref, g_ref, carry_ref):
-            _bwd_pair_kernel(row_tile, dy_ref.at[0],
-                             wl_ref.at[0], wc_ref.at[0], wr_ref.at[0],
-                             g_ref.at[0], carry_ref)
-
-        call = pl.pallas_call(
-            kernel,
-            grid=(2, g_dim, n_tiles),
-            in_specs=[data_spec, wt_spec, wt_spec, wt_spec],
-            out_specs=data_spec,
-            out_shape=jax.ShapeDtypeStruct((2, g_dim, h, w), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((3, 1, w), jnp.float32)],
-            compiler_params=CompilerParams(
-                dimension_semantics=("arbitrary",) * 3),
-            interpret=interpret,
-        )
-        with _launch_span("gspn_pair_bwd", plan, dy2.dtype, g_dim, h, w):
-            return call(dy2, wl2, wc2, wr2)
-
-    wt_spec = pl.BlockSpec((1, gw, row_tile, w),
-                           lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-    data_spec = pl.BlockSpec((1, g_dim, row_tile, w),
-                             lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-
-    def kernel(dy_ref, wl_ref, wc_ref, wr_ref, g_ref, carry_ref):
-        _bwd_pair_kernel_staged(row_tile, cpw, dy_ref.at[0],
-                                wl_ref.at[0], wc_ref.at[0], wr_ref.at[0],
-                                g_ref.at[0], carry_ref)
-
-    call = pl.pallas_call(
-        kernel,
-        grid=(2, n_tiles),
-        in_specs=[data_spec, wt_spec, wt_spec, wt_spec],
-        out_specs=data_spec,
-        out_shape=jax.ShapeDtypeStruct((2, g_dim, h, w), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((3, g_dim, 1, w), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",) * 2),
-        interpret=interpret,
-    )
-    with _launch_span("gspn_pair_bwd", plan, dy2.dtype, g_dim, h, w):
-        return call(dy2, wl2, wc2, wr2)
+    gw = g_dim // spec.channels_per_weight
+    plan = autotune.plan_for_spec(spec, h, w, c=g_dim)
+    out = launch_scan(
+        spec, plan,
+        [(_stacked(dy2), g_dim), (_stacked(wl2), gw), (_stacked(wc2), gw),
+         (_stacked(wr2), gw)],
+        adjoint=True, n_dirs=2, reversed_dirs=(0,), out_dtype=jnp.float32,
+        name="gspn_pair_bwd")
+    return out.reshape(2, g_dim, h, w)
 
 
 # ---------------------------------------------------------------------------
@@ -422,7 +142,7 @@ def gspn_scan_bidir_bwd_pallas(dy2, wl2, wc2, wr2, *,
 def gspn_scan_quad_pallas(x, taps4, lam4, *, spec: ScanSpec | None = None,
                           channels_per_weight: int = 1,
                           row_tile: int | None = None,
-                          interpret: bool = True,
+                          interpret: bool | None = None,
                           carry_dtype=jnp.float32,
                           pipeline_depth: int | None = None):
     """All four directions in ONE ``pallas_call`` (square H == W only).
@@ -445,76 +165,13 @@ def gspn_scan_quad_pallas(x, taps4, lam4, *, spec: ScanSpec | None = None,
                       channels_per_weight=channels_per_weight,
                       carry_dtype=carry_dtype, interpret=interpret,
                       row_tile=row_tile, pipeline_depth=pipeline_depth)
-    cpw = spec.channels_per_weight
-    gw = g // cpw
-    carry_dtype = jnp.dtype(spec.carry_dtype)
-    interpret = spec.interpret
-    plan = _pair_plan(spec, h, w, g)
-    row_tile, pipeline_depth = plan.row_tile, plan.pipeline_depth
-    assert h % row_tile == 0
-    assert pipeline_depth in (1, 2), pipeline_depth
-    n_tiles = h // row_tile
-
-    xx = jnp.stack([x, jnp.swapaxes(x, -1, -2)])        # (2, G, N, N)
-
-    def ti_eff(d, ti):
-        return jnp.where(d % 2 == 0, ti, n_tiles - 1 - ti)
-
-    if pipeline_depth == 1:
-        xx_spec = pl.BlockSpec(
-            (1, 1, row_tile, w),
-            lambda d, gi, ti: (d // 2, gi, ti_eff(d, ti), 0))
-        wt_spec = pl.BlockSpec(
-            (1, 1, row_tile, w),
-            lambda d, gi, ti: (d, gi // cpw, ti_eff(d, ti), 0))
-        lam_spec = pl.BlockSpec((1, 1, row_tile, w),
-                                lambda d, gi, ti: (d, gi, ti_eff(d, ti), 0))
-        out_spec = pl.BlockSpec((1, 1, row_tile, w),
-                                lambda d, gi, ti: (d, gi, ti_eff(d, ti), 0))
-
-        def kernel(x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref, carry_ref):
-            _kernel(row_tile, x_ref.at[0],
-                    wl_ref.at[0], wc_ref.at[0], wr_ref.at[0], lam_ref.at[0],
-                    o_ref.at[0], carry_ref)
-
-        call = pl.pallas_call(
-            kernel,
-            grid=(4, g, n_tiles),
-            in_specs=[xx_spec, wt_spec, wt_spec, wt_spec, lam_spec],
-            out_specs=out_spec,
-            out_shape=jax.ShapeDtypeStruct((4, g, h, w), x.dtype),
-            scratch_shapes=[pltpu.VMEM((1, w), carry_dtype)],
-            compiler_params=CompilerParams(
-                dimension_semantics=("arbitrary",) * 3),
-            interpret=interpret,
-        )
-        with _launch_span("gspn_quad_fwd", plan, x.dtype, g, h, w):
-            return call(xx, taps4["wl"], taps4["wc"], taps4["wr"], lam4)
-
-    xx_spec = pl.BlockSpec((1, g, row_tile, w),
-                           lambda d, ti: (d // 2, 0, ti_eff(d, ti), 0))
-    wt_spec = pl.BlockSpec((1, gw, row_tile, w),
-                           lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-    lam_spec = pl.BlockSpec((1, g, row_tile, w),
-                            lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-    out_spec = pl.BlockSpec((1, g, row_tile, w),
-                            lambda d, ti: (d, 0, ti_eff(d, ti), 0))
-
-    def kernel(x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref, carry_ref):
-        _kernel_staged(row_tile, cpw, x_ref.at[0],
-                       wl_ref.at[0], wc_ref.at[0], wr_ref.at[0],
-                       lam_ref.at[0], o_ref.at[0], carry_ref)
-
-    call = pl.pallas_call(
-        kernel,
-        grid=(4, n_tiles),
-        in_specs=[xx_spec, wt_spec, wt_spec, wt_spec, lam_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((4, g, h, w), x.dtype),
-        scratch_shapes=[pltpu.VMEM((g, 1, w), carry_dtype)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",) * 2),
-        interpret=interpret,
-    )
-    with _launch_span("gspn_quad_fwd", plan, x.dtype, g, h, w):
-        return call(xx, taps4["wl"], taps4["wc"], taps4["wr"], lam4)
+    gw = g // spec.channels_per_weight
+    plan = autotune.plan_for_spec(spec, h, w, c=g)
+    xx = jnp.concatenate([x, jnp.swapaxes(x, -1, -2)])   # (2G, N, N)
+    out = launch_scan(
+        spec, plan,
+        [(xx, g), (_stacked(taps4["wl"]), gw), (_stacked(taps4["wc"]), gw),
+         (_stacked(taps4["wr"]), gw), (_stacked(lam4), g)],
+        adjoint=False, n_dirs=4, reversed_dirs=(1, 3), out_dtype=x.dtype,
+        name="gspn_quad_fwd")
+    return out.reshape(4, g, h, w)

@@ -3,8 +3,8 @@
 TPU adaptation of the paper's single-CUDA-kernel design (DESIGN.md §2):
 
 * the whole scan runs inside **one** ``pl.pallas_call`` — the grid walks
-  ``(G, H_tiles)`` sequentially and the row loop runs *inside* the kernel,
-  eliminating the per-step dispatches of GSPN-1;
+  ``(D, G, H_tiles)`` sequentially and the row loop runs *inside* the
+  kernel, eliminating the per-step dispatches of GSPN-1;
 * the previous row's hidden state is staged in a **VMEM scratch carry**
   that persists across sequential grid steps — the TPU analogue of the
   paper's shared-memory staging of ``h[i-1]`` (it never round-trips to HBM);
@@ -18,17 +18,29 @@ TPU adaptation of the paper's single-CUDA-kernel design (DESIGN.md §2):
 * the channel-slice grid axis plays the role of the paper's 2D thread
   blocks (spatial × cSlice).
 
+ONE kernel body (:func:`_scan_kernel`) serves every launch: the forward
+and adjoint recurrences, one direction or a fused opposite pair / quad
+(``gspn_multidir``), at either pipeline depth (DESIGN.md §12).  A
+direction that walks rows last→first does so by index arithmetic — its
+tiles in reverse through the ``index_map``, its rows in reverse inside
+the kernel — so no flipped copy of any operand exists.
+
 Array layout: ``x, lam, out: (G, H, W)``; ``wl, wc, wr: (G_w, H, W)`` with
 ``G = G_w * channels_per_weight``.  All kernels compute in f32 and cast the
 output back to the input dtype; the VMEM carry row is kept in
 ``carry_dtype`` (f32 under the default mixed-precision policy, DESIGN.md
 §10) while the streamed tiles take whatever dtype the operands carry, so
-bf16 operands halve the streamed working set and unlock 2× larger row
-tiles from the tuner.
+bf16 operands halve the streamed working set.
+
+Interpret mode is used only where the backend cannot compile a kernel:
+unless a ``ScanSpec`` sets ``interpret`` explicitly, every launch picks
+the Mosaic kernel when lowered for a TPU and the interpreter otherwise
+(:func:`pallas_call`).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -40,10 +52,6 @@ from repro import obs
 from repro.kernels import autotune, tuning
 from repro.kernels.spec import ScanSpec
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x; accept
-# either so the kernels run on the container's pinned jax.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 DEFAULT_ROW_TILE = 256
 
 
@@ -54,13 +62,11 @@ def pick_row_tile(h: int, cap: int = DEFAULT_ROW_TILE, *, w: int = 128,
     """Heuristic row-tile choice (the tuner's fallback tier).
 
     Thin wrapper (old signature preserved) over the single VMEM-aware
-    implementation in :func:`repro.kernels.tuning.pick_row_tile`: largest
-    power-of-two divisor of ``h`` not exceeding ``cap`` whose streamed
-    working set fits the VMEM budget.  ``dtype_bytes`` is the STREAMED
-    dtype; ``carry_dtype_bytes`` the VMEM carry's.  Launch sites no longer
-    call this directly — they go through ``autotune.plan_for_spec``, which
-    prefers a measured cache entry and falls back to this accounting
-    (DESIGN.md §11/§12).
+    implementation in :func:`repro.kernels.tuning.pick_row_tile`.
+    ``dtype_bytes`` is the STREAMED dtype; ``carry_dtype_bytes`` the VMEM
+    carry's.  Launch sites no longer call this directly — they go through
+    ``autotune.plan_for_spec``, which prefers a measured cache entry and
+    falls back to this accounting (DESIGN.md §11/§12).
     """
     return tuning.pick_row_tile(h, w, dtype_bytes, cap=cap,
                                 n_streams=n_streams,
@@ -68,60 +74,31 @@ def pick_row_tile(h: int, cap: int = DEFAULT_ROW_TILE, *, w: int = 128,
                                 pipeline_depth=pipeline_depth).row_tile
 
 
-def _row(ref, r):
-    """Load row ``r`` of a (1, TH, W) block as a (1, W) f32 tile."""
-    return ref[0, pl.dslice(r, 1), :].astype(jnp.float32)
+def pallas_call(kernel, *, interpret: bool | None, **kwargs):
+    """``pl.pallas_call`` with the interpret decision made per platform.
 
-
-def _shift_right(v):
-    """(..., W): v[..., j] -> v[..., j-1], position 0 becomes 0."""
-    rolled = jnp.roll(v, 1, axis=-1)
-    idx = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
-    return jnp.where(idx == 0, 0.0, rolled)
-
-
-def _shift_left(v):
-    """(..., W): v[..., j] -> v[..., j+1], last position becomes 0."""
-    rolled = jnp.roll(v, -1, axis=-1)
-    idx = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
-    return jnp.where(idx == v.shape[-1] - 1, 0.0, rolled)
-
-
-# ---------------------------------------------------------------------------
-# Depth-2 staging helpers (DESIGN.md §12).
-#
-# The staged pipeline widens every streamed block to f32 ONCE per grid
-# step (one bulk convert instead of a per-row widen through the narrow-
-# dtype retiling path), broadcasts channel-shared weights in VMEM, runs
-# the row recurrence as a ``lax.scan`` over the STAGED VALUES — so the
-# sequential loop touches no ref at all: no per-row masked loads, no
-# per-row stores — and writes the scan's stacked f32 output stage back
-# through ONE bulk downcast.  Between grid steps the BlockSpec revolving
-# buffers keep the next tile's DMA in flight while the current tile
-# computes; the f32 carry block never leaves VMEM.
-# ---------------------------------------------------------------------------
-
-def _stage_widen(ref, cpw: int = 1):
-    """Bulk-load a (Gw, T, W) block as f32, broadcast to (Gw*cpw, T, W)."""
-    staged = ref[...].astype(jnp.float32)
-    if cpw > 1:
-        gw = staged.shape[0]
-        staged = jnp.broadcast_to(staged[:, None],
-                                  (gw, cpw) + staged.shape[1:])
-        staged = staged.reshape((gw * cpw,) + staged.shape[2:])
-    return staged
-
-
-def _stage_rows(ref, cpw: int = 1):
-    """Stage a (Gw, T, W) block as (T, G, W) f32 scan inputs."""
-    return jnp.swapaxes(_stage_widen(ref, cpw), 0, 1)
+    An explicit ``interpret`` (a ``ScanSpec`` override — the compile
+    tests pass ``False``) wins.  ``None`` defers the choice to lowering
+    (``lax.platform_dependent``): the Mosaic kernel for a TPU — on the
+    chip, or ahead of time for a described one — and the interpreter for
+    every other backend.  So no TPU lowering ever reaches the
+    interpreter, and nothing on the CPU needs a flag."""
+    if interpret is not None:
+        return pl.pallas_call(kernel, interpret=interpret, **kwargs)
+    compiled = pl.pallas_call(kernel, interpret=False, **kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+    return lambda *args: jax.lax.platform_dependent(
+        *args, tpu=compiled, default=interpreted)
 
 
 def _masked_shifts(shape):
-    """Edge-masked lane shifts with the iota/compare hoisted OUT of the
-    sequential loop: the masks are built once per grid step, so each scan
-    step pays one roll + one select per shift instead of re-deriving the
-    edge mask.  Identical values to ``_shift_right``/``_shift_left``."""
+    """Edge-masked lane shifts ``sr: v[j] -> v[j-1]`` and ``sl: v[j] ->
+    v[j+1]`` (the vacated edge lane becomes 0), with the iota/compare
+    hoisted OUT of the sequential loop: the masks are built once per grid
+    step, so each row step pays one roll + one select per shift.  A
+    one-lane row has no neighbours (and Mosaic no zero-width slice)."""
+    if shape[-1] == 1:
+        return jnp.zeros_like, jnp.zeros_like
     idx = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
     first, last = idx == 0, idx == shape[-1] - 1
 
@@ -134,98 +111,260 @@ def _masked_shifts(shape):
     return sr, sl
 
 
-def _dir_scan(step, init, xs, reverse):
-    """``lax.scan`` whose row direction follows a TRACED flag: the staged
-    multidir kernels pick the reverse walk per grid step (direction axis)
-    without flipping any staged data — ``reverse=True`` consumes rows
-    last→first and stacks each output at its row's natural position,
-    exactly the legacy kernels' ``r_eff`` indexing (identical values row
-    for row, so depth parity stays bitwise)."""
-    return jax.lax.cond(
-        reverse,
-        lambda: jax.lax.scan(step, init, xs, reverse=True),
-        lambda: jax.lax.scan(step, init, xs))
-
-
 # ---------------------------------------------------------------------------
-# Forward kernel.
+# The two recurrences, written once for both depths.  A step maps the f32
+# carry tuple and one f32 row of every input stream to (new carry, output
+# row).  ``lam*x`` stays inside the step on purpose: hoisting it to a bulk
+# multiply changes which mul/add pairs the CPU backend contracts into
+# FMAs, breaking the bitwise depth-1 ≡ depth-2 agreement in f32 streams.
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(row_tile, chunk_tiles,
-                x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref, carry_ref):
-    t = pl.program_id(1)
+def _fwd_step(sr, sl, carry, row):
+    """h[i] = wl·h[i-1, j-1] + wc·h[i-1, j] + wr·h[i-1, j+1] + λ·x."""
+    (h_prev,) = carry
+    x_r, wl_r, wc_r, wr_r, lam_r = row
+    h_new = (
+        wl_r * sr(h_prev)
+        + wc_r * h_prev
+        + wr_r * sl(h_prev)
+        + lam_r * x_r
+    )
+    return (h_new,), h_new
 
-    @pl.when(t % chunk_tiles == 0)
+
+def _bwd_step(sr, sl, carry, row):
+    """Adjoint: the carry holds the three tap·adjoint products of the
+    previously processed (next-in-scan-order) row:
+        g[i] = dy[i] + shift_left(wl[i+1]·g[i+1]) + wc[i+1]·g[i+1]
+                     + shift_right(wr[i+1]·g[i+1])"""
+    prod_l, prod_c, prod_r = carry
+    dy_r, wl_r, wc_r, wr_r = row
+    g_row = dy_r + sl(prod_l) + prod_c + sr(prod_r)
+    return (wl_r * g_row, wc_r * g_row, wr_r * g_row), g_row
+
+
+@dataclasses.dataclass(frozen=True)
+class _KernelCfg:
+    adjoint: bool            # _bwd_step (4 inputs, 3 carry rows)
+    depth: int               # 1: one plane per grid step; 2: all planes
+    row_tile: int
+    group: int               # depth 1: rows widened per bulk load
+    chunk_tiles: int         # carry resets every chunk_tiles grid steps
+    n_dirs: int
+    reversed_dirs: tuple     # directions that walk rows last→first
+    bcast: tuple             # depth 2: plane broadcast factor per input
+
+
+def _dir_reversed(reversed_dirs, n_dirs: int, d):
+    """Whether grid direction ``d`` walks last→first — a static bool when
+    every direction agrees."""
+    if not reversed_dirs:
+        return False
+    if len(reversed_dirs) == n_dirs:
+        return True
+    return functools.reduce(jnp.logical_or, [d == k for k in reversed_dirs])
+
+
+def _flip(i, n, reverse):
+    """Index ``i`` of ``n``, counted from the end when ``reverse``."""
+    return (n - 1 - i) if reverse is True else \
+        i if reverse is False else jnp.where(reverse, n - 1 - i, i)
+
+
+def _walk_rows(cfg, reverse, step, ins, out, stages, init):
+    """Depth-1 row recurrence over one plane's ``(1, T, W)`` tile.
+
+    f32 streams are read and written row by row in place.  A narrow
+    stream (bf16) is widened ``group`` rows at a time into an f32 stage
+    by one aligned bulk load — a single-row load or store of a packed
+    tile has no Mosaic lowering — and a narrow output is gathered in an
+    f32 stage and written back by one bulk downcast per group."""
+    stages = list(stages)
+    in_st = [stages.pop(0) if r.dtype != jnp.float32 else None for r in ins]
+    out_st = stages.pop(0) if out.dtype != jnp.float32 else None
+    group = cfg.group
+    n_groups = cfg.row_tile // group
+
+    def group_body(gi, carry):
+        base = _flip(gi, n_groups, reverse) * group
+        if n_groups > 1:
+            base = pl.multiple_of(base, group)
+        for ref, st in zip(ins, in_st):
+            if st is not None:
+                st[...] = ref[0, pl.ds(base, group), :].astype(jnp.float32)
+
+        def row_body(ki, c):
+            k = _flip(ki, group, reverse)
+            row = [st[pl.ds(k, 1), :] if st is not None
+                   else ref[0, pl.ds(base + k, 1), :]
+                   for ref, st in zip(ins, in_st)]
+            c, y = step(c, row)
+            if out_st is not None:
+                out_st[pl.ds(k, 1), :] = y
+            else:
+                out[0, pl.ds(base + k, 1), :] = y
+            return c
+
+        carry = jax.lax.fori_loop(0, group, row_body, carry)
+        if out_st is not None:
+            out[0, pl.ds(base, group), :] = out_st[...].astype(out.dtype)
+        return carry
+
+    if n_groups == 1:
+        return group_body(0, init)
+    return jax.lax.fori_loop(0, n_groups, group_body, init)
+
+
+def _walk_staged(cfg, reverse, step, ins, out, stages, init):
+    """Depth-2 row recurrence over ALL planes of a ``(P, T, W)`` tile.
+
+    Each input block is widened to f32 once, its channel-shared weight
+    planes broadcast, and stored transposed as a ``(T, G, W)`` stage: row
+    ``r`` of every plane is then one ``(G, W)`` slab at an untiled
+    leading index, so the G planes share sublanes and the row loop needs
+    no sublane-offset access at all.  The f32 output stage is written
+    back by one transpose and one bulk downcast per tile."""
+    *in_st, out_st = stages
+    for ref, st, rep in zip(ins, in_st, cfg.bcast):
+        v = ref[...].astype(jnp.float32)
+        if rep > 1:
+            p = v.shape[0]
+            v = jnp.broadcast_to(v[:, None], (p, rep) + v.shape[1:])
+            v = v.reshape((p * rep,) + v.shape[2:])
+        st[...] = jnp.swapaxes(v, 0, 1)
+
+    def body(i, c):
+        r = _flip(i, cfg.row_tile, reverse)
+        c, y = step(c, [st[r] for st in in_st])
+        out_st[r] = y
+        return c
+
+    carry = jax.lax.fori_loop(0, cfg.row_tile, body, init)
+    out[...] = jnp.swapaxes(out_st[...], 0, 1).astype(out.dtype)
+    return carry
+
+
+def _scan_kernel(cfg: _KernelCfg, *refs):
+    n_in = 4 if cfg.adjoint else 5
+    ins, out, carry_ref = refs[:n_in], refs[n_in], refs[n_in + 1]
+    stages = refs[n_in + 2:]
+    tile_axis = 2 if cfg.depth == 1 else 1
+
+    @pl.when(pl.program_id(tile_axis) % cfg.chunk_tiles == 0)
     def _reset():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    def body(r, h_prev):
-        h_new = (
-            _row(wl_ref, r) * _shift_right(h_prev)
-            + _row(wc_ref, r) * h_prev
-            + _row(wr_ref, r) * _shift_left(h_prev)
-            + _row(lam_ref, r) * _row(x_ref, r)
-        )
-        o_ref[0, pl.dslice(r, 1), :] = h_new.astype(o_ref.dtype)
-        return h_new
-
+    sr, sl = _masked_shifts(carry_ref.shape[1:])
+    step = functools.partial(_bwd_step if cfg.adjoint else _fwd_step, sr, sl)
+    reverse = _dir_reversed(cfg.reversed_dirs, cfg.n_dirs, pl.program_id(0))
     # The row recurrence runs in f32 regardless of the streamed dtype; the
     # cross-tile carry is stored in the scratch's dtype (carry_dtype).
-    carry_ref[...] = jax.lax.fori_loop(
-        0, row_tile, body,
-        carry_ref[...].astype(jnp.float32)).astype(carry_ref.dtype)
+    init = tuple(carry_ref[i].astype(jnp.float32)
+                 for i in range(carry_ref.shape[0]))
+    walk = _walk_rows if cfg.depth == 1 else _walk_staged
+    carry = walk(cfg, reverse, step, ins, out, stages, init)
+    for i, c in enumerate(carry):
+        carry_ref[i] = c.astype(carry_ref.dtype)
 
 
-def _fwd_kernel_staged(row_tile, chunk_tiles, cpw,
-                       x_ref, wl_ref, wc_ref, wr_ref, lam_ref, o_ref,
-                       carry_ref):
-    """Depth-2 forward kernel: all G planes per grid step, staged streams.
+def launch_scan(spec: ScanSpec, plan, operands, *, adjoint: bool,
+                n_dirs: int = 1, reversed_dirs: tuple = (),
+                chunk: int | None = None, out_dtype, name: str):
+    """Build and run ONE fused-scan ``pallas_call``.
 
-    Same f32 recurrence and operation order as ``_fwd_kernel`` vectorised
-    over the plane axis — the two depths are bit-identical (the
-    conformance grid asserts exact agreement).  The recurrence runs as a
-    ``lax.scan`` over the staged rows, so the only ref traffic per grid
-    step is one bulk load per stream and one bulk downcast store."""
-    del row_tile
-    t = pl.program_id(0)
+    ``operands`` are the kernel inputs in step order — fwd ``(x, wl, wc,
+    wr, lam)``, adjoint ``(dy, wl, wc, wr)`` — each ``(array, planes)``
+    with ``array: (k·planes, H, W)`` holding k stacked direction slices
+    (k = 1 for an input shared by every direction, e.g. the pair's x).
+    Data streams have G planes, channel-shared weights G_w.  Returns
+    ``(n_dirs·G, H, W)`` in ``out_dtype``; direction ``d`` occupies rows
+    ``d·G … (d+1)·G``.  ``reversed_dirs`` walk their rows last→first;
+    ``chunk`` resets the carry every ``chunk`` rows (GSPN-local
+    segments)."""
+    _, h, w = operands[0][0].shape
+    g = operands[0][1]
+    t, depth = plan.row_tile, plan.pipeline_depth
+    assert depth in (1, 2), depth
+    chunk = h if chunk is None else chunk
+    assert h % chunk == 0 and chunk % t == 0, (h, chunk, t)
+    n_tiles = h // t
+    rev = tuple(sorted(set(reversed_dirs)))
 
-    @pl.when(t % chunk_tiles == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
+    def tile_of(d, ti):
+        return _flip(ti, n_tiles, _dir_reversed(rev, n_dirs, d))
 
-    xs = _stage_rows(x_ref)                         # (T, G, W) f32
-    lams = _stage_rows(lam_ref)
-    wls = _stage_rows(wl_ref, cpw)                  # (Gw,T,W) -> (T,G,W)
-    wcs = _stage_rows(wc_ref, cpw)
-    wrs = _stage_rows(wr_ref, cpw)
-    sr, sl = _masked_shifts(xs.shape[1:])
+    def dir_of(arr, planes):
+        per = n_dirs // (arr.shape[0] // planes)
+        return lambda d: d // per
 
-    # NOTE: lam*x stays INSIDE the step on purpose — hoisting it to a bulk
-    # multiply changes which mul/add pairs the CPU backend contracts into
-    # FMAs, breaking the bitwise depth-1 agreement in f32 streams.
-    def step(h_prev, row):
-        x_r, wl_r, wc_r, wr_r, lam_r = row
-        h_new = (
-            wl_r * sr(h_prev)
-            + wc_r * h_prev
-            + wr_r * sl(h_prev)
-            + lam_r * x_r
-        )
-        return h_new, h_new
+    narrow = [a.dtype for a, _ in operands if a.dtype != jnp.float32]
+    if jnp.dtype(out_dtype) != jnp.float32:
+        narrow.append(out_dtype)
+    itemsize = min([jnp.dtype(dt).itemsize for dt in narrow] or [4])
+    carry_rows = 3 if adjoint else 1
+    carry_dtype = jnp.float32 if adjoint else jnp.dtype(spec.carry_dtype)
 
-    h0 = carry_ref[...].astype(jnp.float32)[:, 0, :]         # (G, W)
-    h_last, ys = jax.lax.scan(step, h0, (xs, wls, wcs, wrs, lams))
-    carry_ref[...] = h_last[:, None, :].astype(carry_ref.dtype)
-    # ONE bulk downcast writeback per tile — the per-row narrow-dtype
-    # store was the bf16 cliff (DESIGN.md §12).
-    o_ref[...] = jnp.swapaxes(ys, 0, 1).astype(o_ref.dtype)
+    if depth == 1:
+        group = tuning.stage_rows(t, itemsize, 1) or t
+        in_specs = []
+        for arr, planes in operands:
+            rep, dmap = g // planes, dir_of(arr, planes)
+            in_specs.append(pl.BlockSpec(
+                (1, t, w),
+                lambda d, gi, ti, rep=rep, dmap=dmap, planes=planes:
+                    (dmap(d) * planes + gi // rep, tile_of(d, ti), 0)))
+        out_spec = pl.BlockSpec(
+            (1, t, w), lambda d, gi, ti: (d * g + gi, tile_of(d, ti), 0))
+        grid = (n_dirs, g, n_tiles)
+        stages = [pltpu.VMEM((group, w), jnp.float32) for _ in narrow]
+        carry = pltpu.VMEM((carry_rows, 1, w), carry_dtype)
+        bcast = ()
+    else:
+        group = t
+        in_specs = [pl.BlockSpec(
+            (planes, t, w),
+            lambda d, ti, dmap=dir_of(arr, planes): (dmap(d), tile_of(d, ti),
+                                                     0))
+            for arr, planes in operands]
+        out_spec = pl.BlockSpec((g, t, w),
+                                lambda d, ti: (d, tile_of(d, ti), 0))
+        grid = (n_dirs, n_tiles)
+        stages = [pltpu.VMEM((t, g, w), jnp.float32)
+                  for _ in range(len(operands) + 1)]
+        carry = pltpu.VMEM((carry_rows, g, w), carry_dtype)
+        bcast = tuple(g // planes for _, planes in operands)
 
+    cfg = _KernelCfg(adjoint=adjoint, depth=depth, row_tile=t, group=group,
+                     chunk_tiles=chunk // t, n_dirs=n_dirs,
+                     reversed_dirs=rev, bcast=bcast)
+    call = pallas_call(
+        functools.partial(_scan_kernel, cfg),
+        grid=grid, in_specs=in_specs, out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((n_dirs * g, h, w), out_dtype),
+        scratch_shapes=[carry] + stages,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=tuning.VMEM_BYTES),
+        interpret=spec.interpret)
+    # Traced-launch span (DESIGN.md §13): fires once per jit trace of this
+    # launch site, annotated with the tuner-resolved plan.
+    with obs.trace("kernel.launch", kernel=name, row_tile=t,
+                   pipeline_depth=depth,
+                   dtype=str(jnp.dtype(operands[0][0].dtype)),
+                   g=g, h=h, w=w):
+        return call(*(a for a, _ in operands))
+
+
+# ---------------------------------------------------------------------------
+# Single-direction entry points.
+# ---------------------------------------------------------------------------
 
 def gspn_scan_fwd_pallas(x, wl, wc, wr, lam, *,
                          spec: ScanSpec | None = None,
                          channels_per_weight: int = 1,
                          chunk: int | None = None, row_tile: int | None = None,
-                         interpret: bool = True, carry_dtype=jnp.float32,
+                         interpret: bool | None = None,
+                         carry_dtype=jnp.float32,
                          pipeline_depth: int | None = None):
     """Fused forward line scan.  Returns h: (G, H, W) in x.dtype.
 
@@ -235,10 +374,8 @@ def gspn_scan_fwd_pallas(x, wl, wc, wr, lam, *,
     VMEM carry row persists in ``spec.carry_dtype`` (f32 by default —
     the mixed-precision policy's accumulator discipline, DESIGN.md §10).
     ``spec.pipeline_depth`` selects the kernel structure (DESIGN.md §12):
-    1 walks planes × tiles with per-row loads/stores (the classic
-    stream); 2 blocks all planes into each grid step and stages the
-    streams in f32 — bulk widen on load, one bulk downcast writeback —
-    so narrow dtypes never pay a per-row retiling penalty.  ``None``
+    1 walks planes × tiles row by row; 2 blocks all planes into each grid
+    step and stages the streams in f32 ``(T, G, W)`` layout.  ``None``
     resolves both the tile and the depth through the autotuner (measured
     cache entry keyed on the spec's canonical serialization, heuristic
     fallback).
@@ -253,204 +390,40 @@ def gspn_scan_fwd_pallas(x, wl, wc, wr, lam, *,
     # entry, and it streams whatever dtype the operands carry.
     spec = spec.with_(direction="fwd", impl="pallas",
                       stream_dtype=str(jnp.dtype(x.dtype)))
-    cpw = spec.channels_per_weight
-    gw = g // cpw
-    assert wl.shape[0] * cpw == g, (wl.shape, g, cpw)
+    gw = g // spec.channels_per_weight
+    assert wl.shape[0] == gw, (wl.shape, g, spec.channels_per_weight)
     chunk = h if chunk is None else chunk
-    assert h % chunk == 0, (h, chunk)
-    carry_dtype = jnp.dtype(spec.carry_dtype)
-    interpret = spec.interpret
     plan = autotune.plan_for_spec(spec, min(h, chunk), w, c=g)
-    row_tile, pipeline_depth = plan.row_tile, plan.pipeline_depth
-    assert chunk % row_tile == 0, (chunk, row_tile)
-    assert pipeline_depth in (1, 2), pipeline_depth
-    chunk_tiles = chunk // row_tile
-
-    # Traced-launch span (DESIGN.md §13): fires once per jit trace of this
-    # launch site, annotated with the tuner-resolved plan.
-    with obs.trace("kernel.launch", kernel="gspn_scan_fwd",
-                   row_tile=row_tile, pipeline_depth=pipeline_depth,
-                   dtype=str(jnp.dtype(x.dtype)), g=g, h=h, w=w):
-        if pipeline_depth == 1:
-            data_spec = pl.BlockSpec((1, row_tile, w),
-                                     lambda gi, ti: (gi, ti, 0))
-            wt_spec = pl.BlockSpec((1, row_tile, w),
-                                   lambda gi, ti: (gi // cpw, ti, 0))
-            return pl.pallas_call(
-                functools.partial(_fwd_kernel, row_tile, chunk_tiles),
-                grid=(g, h // row_tile),
-                in_specs=[data_spec, wt_spec, wt_spec, wt_spec, data_spec],
-                out_specs=data_spec,
-                out_shape=jax.ShapeDtypeStruct((g, h, w), x.dtype),
-                scratch_shapes=[pltpu.VMEM((1, w), carry_dtype)],
-                compiler_params=CompilerParams(
-                    dimension_semantics=("arbitrary", "arbitrary"),
-                ),
-                interpret=interpret,
-            )(x, wl, wc, wr, lam)
-
-        data_spec = pl.BlockSpec((g, row_tile, w), lambda ti: (0, ti, 0))
-        wt_spec = pl.BlockSpec((gw, row_tile, w), lambda ti: (0, ti, 0))
-        return pl.pallas_call(
-            functools.partial(_fwd_kernel_staged, row_tile, chunk_tiles, cpw),
-            grid=(h // row_tile,),
-            in_specs=[data_spec, wt_spec, wt_spec, wt_spec, data_spec],
-            out_specs=data_spec,
-            out_shape=jax.ShapeDtypeStruct((g, h, w), x.dtype),
-            scratch_shapes=[pltpu.VMEM((g, 1, w), carry_dtype)],
-            compiler_params=CompilerParams(
-                dimension_semantics=("arbitrary",),
-            ),
-            interpret=interpret,
-        )(x, wl, wc, wr, lam)
-
-
-# ---------------------------------------------------------------------------
-# Backward (adjoint) kernel.
-#
-# Runs on H-flipped arrays so the sequential grid walks rows from last to
-# first.  The carry holds the three tap*adjoint products of the previously
-# processed (i.e. next-in-original-order) row:
-#     g[i] = dy[i] + shift_left(wl[i+1]*g[i+1]) + wc[i+1]*g[i+1]
-#                  + shift_right(wr[i+1]*g[i+1])
-# ---------------------------------------------------------------------------
-
-def _bwd_kernel(row_tile, chunk_tiles,
-                dy_ref, wl_ref, wc_ref, wr_ref, g_ref, carry_ref):
-    t = pl.program_id(1)
-
-    @pl.when(t % chunk_tiles == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
-
-    def body(r, _):
-        prod_l = carry_ref[0, :, :]
-        prod_c = carry_ref[1, :, :]
-        prod_r = carry_ref[2, :, :]
-        g_row = (
-            _row(dy_ref, r)
-            + _shift_left(prod_l)
-            + prod_c
-            + _shift_right(prod_r)
-        )
-        g_ref[0, pl.dslice(r, 1), :] = g_row.astype(g_ref.dtype)
-        carry_ref[0, :, :] = _row(wl_ref, r) * g_row
-        carry_ref[1, :, :] = _row(wc_ref, r) * g_row
-        carry_ref[2, :, :] = _row(wr_ref, r) * g_row
-        return 0
-
-    jax.lax.fori_loop(0, row_tile, body, 0)
-
-
-def _bwd_kernel_staged(row_tile, chunk_tiles, cpw,
-                       dy_ref, wl_ref, wc_ref, wr_ref, g_ref, carry_ref):
-    """Depth-2 adjoint kernel: all planes per grid step, staged streams.
-    Same f32 recurrence and operation order as ``_bwd_kernel`` vectorised
-    over the plane axis (the three tap·adjoint carry rows ride the
-    ``lax.scan`` carry instead of round-tripping through scratch —
-    identical f32 values either way)."""
-    del row_tile
-    t = pl.program_id(0)
-
-    @pl.when(t % chunk_tiles == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
-
-    dys = _stage_rows(dy_ref)                       # (T, G, W) f32
-    wls = _stage_rows(wl_ref, cpw)
-    wcs = _stage_rows(wc_ref, cpw)
-    wrs = _stage_rows(wr_ref, cpw)
-    sr, sl = _masked_shifts(dys.shape[1:])
-
-    def step(prods, row):
-        dy_r, wl_r, wc_r, wr_r = row
-        prod_l, prod_c, prod_r = prods
-        g_row = (
-            dy_r
-            + sl(prod_l)
-            + prod_c
-            + sr(prod_r)
-        )
-        return (wl_r * g_row, wc_r * g_row, wr_r * g_row), g_row
-
-    p0 = (carry_ref[0][:, 0, :], carry_ref[1][:, 0, :],
-          carry_ref[2][:, 0, :])
-    prods, ys = jax.lax.scan(step, p0, (dys, wls, wcs, wrs))
-    carry_ref[0], carry_ref[1], carry_ref[2] = \
-        (p[:, None, :] for p in prods)
-    g_ref[...] = jnp.swapaxes(ys, 0, 1).astype(g_ref.dtype)
+    return launch_scan(spec, plan,
+                       [(x, g), (wl, gw), (wc, gw), (wr, gw), (lam, g)],
+                       adjoint=False, chunk=chunk, out_dtype=x.dtype,
+                       name="gspn_scan_fwd")
 
 
 def gspn_scan_bwd_pallas(dy, wl, wc, wr, *, spec: ScanSpec | None = None,
                          channels_per_weight: int = 1,
                          chunk: int | None = None, row_tile: int | None = None,
-                         interpret: bool = True,
+                         interpret: bool | None = None,
                          pipeline_depth: int | None = None):
-    """Adjoint scan.  Inputs are in ORIGINAL orientation; flipping is done
-    here.  Returns g = dL/dh pre-output-layer: (G, H, W) f32.
-    ``pipeline_depth=2`` is the staged pipeline (DESIGN.md §12)."""
+    """Adjoint scan: walks rows last→first (reverse tile order through
+    the index map, reverse row order in the kernel — no flipped copies).
+    Returns g = dL/dh pre-output-layer: (G, H, W) f32."""
     g_dim, h, w = dy.shape
     if spec is None:
         spec = ScanSpec(channels_per_weight=channels_per_weight,
                         row_tile=row_tile, pipeline_depth=pipeline_depth,
                         interpret=interpret)
-    # The streamed operands are dy + the three taps (their real dtype —
-    # bf16 streams unlock 2× larger row tiles); the adjoint carry is three
-    # f32 tap·adjoint rows regardless of the policy (the "bwd" direction
-    # leg encodes both the 5-stream count and the 3-row carry).
+    # The streamed operands are dy + the three taps (their real dtype);
+    # the adjoint carry is three f32 tap·adjoint rows regardless of the
+    # policy (the "bwd" direction leg encodes both the 5-stream count and
+    # the 3-row carry).
     spec = spec.with_(direction="bwd", impl="pallas",
                       stream_dtype=str(jnp.dtype(dy.dtype)),
                       carry_dtype="float32")
-    cpw = spec.channels_per_weight
-    gw = g_dim // cpw
+    gw = g_dim // spec.channels_per_weight
     chunk = h if chunk is None else chunk
-    assert h % chunk == 0, (h, chunk)
-    interpret = spec.interpret
     plan = autotune.plan_for_spec(spec, min(h, chunk), w, c=g_dim)
-    row_tile, pipeline_depth = plan.row_tile, plan.pipeline_depth
-    assert pipeline_depth in (1, 2), pipeline_depth
-    chunk_tiles = chunk // row_tile
-
-    dy_f = jnp.flip(dy, axis=1)
-    wl_f = jnp.flip(wl, axis=1)
-    wc_f = jnp.flip(wc, axis=1)
-    wr_f = jnp.flip(wr, axis=1)
-
-    with obs.trace("kernel.launch", kernel="gspn_scan_bwd",
-                   row_tile=row_tile, pipeline_depth=pipeline_depth,
-                   dtype=str(jnp.dtype(dy.dtype)), g=g_dim, h=h, w=w):
-        if pipeline_depth == 1:
-            data_spec = pl.BlockSpec((1, row_tile, w),
-                                     lambda gi, ti: (gi, ti, 0))
-            wt_spec = pl.BlockSpec((1, row_tile, w),
-                                   lambda gi, ti: (gi // cpw, ti, 0))
-            g_f = pl.pallas_call(
-                functools.partial(_bwd_kernel, row_tile, chunk_tiles),
-                grid=(g_dim, h // row_tile),
-                in_specs=[data_spec, wt_spec, wt_spec, wt_spec],
-                out_specs=data_spec,
-                out_shape=jax.ShapeDtypeStruct((g_dim, h, w), jnp.float32),
-                scratch_shapes=[pltpu.VMEM((3, 1, w), jnp.float32)],
-                compiler_params=CompilerParams(
-                    dimension_semantics=("arbitrary", "arbitrary"),
-                ),
-                interpret=interpret,
-            )(dy_f, wl_f, wc_f, wr_f)
-        else:
-            data_spec = pl.BlockSpec((g_dim, row_tile, w),
-                                     lambda ti: (0, ti, 0))
-            wt_spec = pl.BlockSpec((gw, row_tile, w), lambda ti: (0, ti, 0))
-            g_f = pl.pallas_call(
-                functools.partial(_bwd_kernel_staged, row_tile, chunk_tiles,
-                                  cpw),
-                grid=(h // row_tile,),
-                in_specs=[data_spec, wt_spec, wt_spec, wt_spec],
-                out_specs=data_spec,
-                out_shape=jax.ShapeDtypeStruct((g_dim, h, w), jnp.float32),
-                scratch_shapes=[pltpu.VMEM((3, g_dim, 1, w), jnp.float32)],
-                compiler_params=CompilerParams(
-                    dimension_semantics=("arbitrary",),
-                ),
-                interpret=interpret,
-            )(dy_f, wl_f, wc_f, wr_f)
-    return jnp.flip(g_f, axis=1)
+    return launch_scan(spec, plan,
+                       [(dy, g_dim), (wl, gw), (wc, gw), (wr, gw)],
+                       adjoint=True, reversed_dirs=(0,), chunk=chunk,
+                       out_dtype=jnp.float32, name="gspn_scan_bwd")

@@ -11,8 +11,9 @@ scans (DESIGN.md §2) are used by ``repro.core.gspn``:
 
 The impl matrix (both entry points):
 
-* ``impl="pallas"``  — the fused Pallas TPU kernel (``interpret=True`` on
-  CPU for validation; compiled Mosaic on real TPUs);
+* ``impl="pallas"``  — the fused Pallas TPU kernel (compiled by Mosaic
+  when lowered for a TPU; the Pallas interpreter on every other backend,
+  which is how the CPU tests validate it);
 * ``impl="multidir"``— the fused opposite-pair Pallas kernel
   (``kernels/gspn_multidir.py``); for the single-direction ``gspn_scan``
   this degenerates to ``pallas`` (same kernel family, one direction);
@@ -88,10 +89,34 @@ def _fwd_dispatch(spec: ScanSpec, x, wl, wc, wr, lam):
         if impl == "pallas":
             return _pk.gspn_scan_fwd_pallas(x, wl, wc, wr, lam, spec=spec)
         if impl == "xla":
-            return _ref.gspn_scan_ref(x, wl, wc, wr, lam)
+            return _ref_in_carry(spec, x, wl, wc, wr, lam)
         if impl == "per_step":
             return _ref.gspn_scan_per_step(x, wl, wc, wr, lam)
     raise ValueError(f"unknown impl {impl!r}")
+
+
+def _ref_in_carry(spec: ScanSpec, x, *ops, **kw):
+    """The XLA reference scan under the spec's precision policy: the
+    recurrence runs in ``carry_dtype`` (f32 under the policy, as in the
+    kernels' VMEM carry) on exactly the stream-dtype operands a kernel
+    reads, and its output is rounded to the stream dtype as a kernel
+    stores it.  ``_rounded`` pins both roundings: XLA's excess-precision
+    rewrites would otherwise drop them inside a fusion, and the XLA path
+    would then compute something the kernel path never sees.  f32
+    streams are untouched."""
+    cd = jnp.dtype(spec.carry_dtype)
+    out = _ref.gspn_scan_ref(*(_rounded(a).astype(cd) for a in (x,) + ops),
+                             **kw)
+    return _rounded(out.astype(x.dtype))
+
+
+def _rounded(a):
+    """``a`` with its narrow-float rounding pinned (identity on values)."""
+    if a.dtype == jnp.float32:
+        return a
+    fi = jnp.finfo(a.dtype)
+    return jax.lax.reduce_precision(a, exponent_bits=fi.nexp,
+                                    mantissa_bits=fi.nmant)
 
 
 def _bwd_adjoint_xla(dy, wl_b, wc_b, wr_b, reverse: bool = True):
@@ -169,7 +194,8 @@ _gspn_core.defvjp(_gspn_core_fwd, _gspn_core_bwd)
 def gspn_scan(x, wl, wc, wr, lam, *, spec: ScanSpec | None = None,
               chunk: int | None = None,
               impl: str = "auto", row_tile: int | None = None,
-              interpret: bool = True, mesh=None, seq_axis: str = "seq",
+              interpret: bool | None = None, mesh=None,
+              seq_axis: str = "seq",
               sp_strategy: str = "auto", carry_dtype="float32",
               sp_boundary_dtype=None, pipeline_depth: int | None = None,
               boundary: str = "one_shot"):
@@ -238,9 +264,9 @@ def _pair_fwd_dispatch(spec: ScanSpec, x, wl2, wc2, wr2, lam2):
         if impl == "multidir":
             return _mk.gspn_scan_bidir_pallas(
                 x, {"wl": wl2, "wc": wc2, "wr": wr2}, lam2, spec=spec)
-        fwd = _ref.gspn_scan_ref(x, wl2[0], wc2[0], wr2[0], lam2[0])
-        rev = _ref.gspn_scan_ref(x, wl2[1], wc2[1], wr2[1], lam2[1],
-                                 reverse=True)
+        fwd = _ref_in_carry(spec, x, wl2[0], wc2[0], wr2[0], lam2[0])
+        rev = _ref_in_carry(spec, x, wl2[1], wc2[1], wr2[1], lam2[1],
+                            reverse=True)
         return jnp.stack([fwd, rev])
 
 
@@ -307,7 +333,8 @@ _gspn_pair_core.defvjp(_gspn_pair_fwd, _gspn_pair_bwd)
 def gspn_scan_pair(x, wl2, wc2, wr2, lam2, *, spec: ScanSpec | None = None,
                    chunk: int | None = None,
                    impl: str = "auto", row_tile: int | None = None,
-                   interpret: bool = True, mesh=None, seq_axis: str = "seq",
+                   interpret: bool | None = None, mesh=None,
+                   seq_axis: str = "seq",
                    sp_strategy: str = "auto", carry_dtype="float32",
                    sp_boundary_dtype=None, pipeline_depth: int | None = None,
                    boundary: str = "one_shot"):

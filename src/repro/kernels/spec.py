@@ -88,7 +88,9 @@ class ScanSpec:
     row_tile: int | None = None        # None = ask the autotuner
     pipeline_depth: int | None = None  # None = tuner/heuristic; 1 | 2
     boundary: str = "one_shot"         # BOUNDARIES
-    interpret: bool = True             # Pallas interpret mode (CPU path)
+    # Pallas interpret mode: None follows the platform being lowered for
+    # (Mosaic on a TPU, the interpreter elsewhere); a bool forces it.
+    interpret: bool | None = None
 
     def __post_init__(self):
         if self.direction not in DIRECTIONS:
@@ -147,7 +149,8 @@ class ScanSpec:
         """Full human-readable identity (test ids, trace annotations)."""
         t = self.row_tile if self.row_tile is not None else "auto"
         d = self.pipeline_depth if self.pipeline_depth is not None else "auto"
-        mode = "interp" if self.interpret else "compiled"
+        mode = {None: "platform", True: "interp",
+                False: "compiled"}[self.interpret]
         return (f"{self.canonical()}|cpw{self.channels_per_weight}"
                 f"|t{t}|d{d}|{mode}")
 

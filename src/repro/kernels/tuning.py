@@ -6,20 +6,23 @@ blocks).  This is the SINGLE row-tile picker: every scan kernel
 thin wrapper over it for the old call signature.
 
 The fused scan keeps per-grid-cell working set
-``(x + wl + wc + wr + lam + out) tiles + carry`` resident in VMEM.  The
-tuner picks the largest power-of-two row tile that (a) divides the scan
-length, (b) keeps the working set inside the VMEM budget, and (c) leaves
-headroom for double-buffered pipelining (factor 2 on the streamed
-operands — Pallas prefetches the next tile while the current one
-computes).
+``(x + wl + wc + wr + lam + out) tiles + f32 stages + carry`` resident in
+VMEM.  The tuner picks the largest admissible row tile that (a) divides
+the scan length, (b) satisfies Mosaic's block-shape rule, (c) keeps the
+working set inside the VMEM budget, and (d) leaves headroom for
+double-buffered pipelining (factor 2 on the streamed operands — Pallas
+prefetches the next tile while the current one computes).
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-# v5e-class VMEM per core; a conservative default budget leaves room for
-# the compiler's own buffers.
+# The scoped-VMEM limit every scan kernel passes to Mosaic
+# (``vmem_limit_bytes``), and the budget the tuner admits working sets
+# against — one number, so an admitted tile is one the compiler accepts.
+# A v5e TensorCore has 128 MiB of VMEM; half of it leaves the compiler
+# room for its own buffers.
 VMEM_BYTES = 64 * 1024 * 1024
 
 
@@ -30,56 +33,101 @@ class TileChoice:
     n_grid_steps: int
 
 
+def sublane_rows(dtype_bytes: int) -> int:
+    """Rows of one native VMEM tile for a streamed dtype: 8 for 4-byte,
+    16 for packed 2-byte, 32 for 1-byte elements."""
+    return 8 * 4 // dtype_bytes
+
+
+def tile_admissible(row_tile: int, h: int, dtype_bytes: int) -> bool:
+    """Mosaic's block-shape rule for the row axis: a row tile is a
+    power-of-two multiple of the dtype's sublane tile, or the whole scan
+    length.  (Every dividing multiple would satisfy Mosaic; powers of two
+    keep the candidate set small.)"""
+    if row_tile < 1 or h % row_tile:
+        return False
+    if row_tile == h:
+        return True
+    return (row_tile % sublane_rows(dtype_bytes) == 0
+            and row_tile & (row_tile - 1) == 0)
+
+
+def admissible_tiles(h: int, dtype_bytes: int, cap: int) -> list[int]:
+    """Ascending admissible row tiles up to ``cap``; the whole scan
+    length when no smaller tile is admissible."""
+    tiles = [t for t in range(1, min(h, cap) + 1)
+             if tile_admissible(t, h, dtype_bytes)]
+    return tiles or [h]
+
+
+def stage_rows(row_tile: int, dtype_bytes: int, pipeline_depth: int) -> int:
+    """Rows of each stream's f32 staging buffer.  Depth 2 stages the
+    whole tile (the transposed ``(T, G, W)`` copy its row loop indexes);
+    depth 1 widens narrow streams one sublane group at a time (the whole
+    tile when the tile is not a multiple of the group); f32 streams at
+    depth 1 are read in place."""
+    if pipeline_depth >= 2:
+        return row_tile
+    if dtype_bytes >= 4:
+        return 0
+    sub = sublane_rows(dtype_bytes)
+    return sub if row_tile % sub == 0 else row_tile
+
+
 def scan_working_set(row_tile: int, w: int, dtype_bytes: int,
                      n_streams: int = 6, double_buffer: bool = True,
                      carry_dtype_bytes: int = 4,
-                     pipeline_depth: int = 1) -> int:
+                     pipeline_depth: int = 1, planes: int = 1) -> int:
     """Bytes resident per grid cell: n_streams streamed tiles (+ their
-    prefetch copies) + the carry row.
+    prefetch copies), their f32 staging buffers, and the carry row.
 
     ``dtype_bytes`` is the STREAMED dtype (bf16 halves every tile);
     ``carry_dtype_bytes`` is the VMEM carry row's dtype, kept separate so
     the accounting stays honest under the mixed-precision policy
     (DESIGN.md §10: bf16 streams, f32 carry).
 
-    ``pipeline_depth=2`` is the explicitly staged pipeline (DESIGN.md
-    §12): every streamed tile additionally keeps an f32 staging copy
-    resident — the widen-on-load input stages plus the f32 out-stage that
-    is written back in one bulk downcast — so the streamed term grows by
-    ``n_streams * row_tile * w * 4`` regardless of the stream dtype.  For
-    bf16 streams this lands the depth-2 footprint exactly on the f32
-    depth-1 footprint (2·2 + 4 = 4·2 bytes per element); for f32 streams
-    the stage is a dead copy that only shrinks the admissible tile, which
-    is why the tuner never emits depth 2 for 4-byte streams.
+    ``pipeline_depth=2`` is the plane-blocked staged pipeline (DESIGN.md
+    §12): each grid step holds ``planes`` (= G) planes of every stream,
+    and each stream keeps a whole-tile f32 staging copy, so every term
+    scales with ``planes``.  At depth 1 a grid step holds one plane;
+    narrow streams add a one-group f32 stage (:func:`stage_rows`).  Rows
+    and lanes are counted as VMEM holds them: padded to the dtype's
+    sublane tile and to 128 lanes, so a 7-wide vision grid costs what
+    a 128-wide one does.
     """
-    tile = row_tile * w * dtype_bytes
+    planes = planes if pipeline_depth >= 2 else 1
     mult = 2 if double_buffer else 1
-    ws = n_streams * tile * mult + w * carry_dtype_bytes
-    if pipeline_depth >= 2:
-        ws += n_streams * row_tile * w * 4
-    return ws
+    lanes = _round_up(w, 128)               # VMEM pads W to whole lanes
+    rows = _round_up(row_tile, sublane_rows(dtype_bytes))
+    ws = n_streams * rows * lanes * dtype_bytes * mult
+    ws += n_streams * stage_rows(row_tile, dtype_bytes, pipeline_depth) \
+        * lanes * 4
+    ws += lanes * carry_dtype_bytes
+    return ws * planes
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def pick_row_tile(h: int, w: int, dtype_bytes: int = 4,
                   vmem_budget: int = VMEM_BYTES, cap: int = 512,
                   n_streams: int = 6,
                   carry_dtype_bytes: int = 4,
-                  pipeline_depth: int = 1) -> TileChoice:
-    """Largest power-of-two divisor of ``h`` whose working set fits."""
-    best = 1
-    t = 1
-    while t * 2 <= cap and h % (t * 2) == 0:
-        t *= 2
-        if scan_working_set(t, w, dtype_bytes, n_streams,
-                            carry_dtype_bytes=carry_dtype_bytes,
-                            pipeline_depth=pipeline_depth) \
-                <= vmem_budget:
-            best = t
-    return TileChoice(row_tile=best,
-                      working_set_bytes=scan_working_set(
-                          best, w, dtype_bytes, n_streams,
-                          carry_dtype_bytes=carry_dtype_bytes,
-                          pipeline_depth=pipeline_depth),
+                  pipeline_depth: int = 1, planes: int = 1) -> TileChoice:
+    """Largest admissible row tile (:func:`tile_admissible`) whose working
+    set fits; the smallest admissible one when none fits."""
+    tiles = admissible_tiles(h, dtype_bytes, cap)
+
+    def ws(t):
+        return scan_working_set(t, w, dtype_bytes, n_streams,
+                                carry_dtype_bytes=carry_dtype_bytes,
+                                pipeline_depth=pipeline_depth,
+                                planes=planes)
+
+    fitting = [t for t in tiles if ws(t) <= vmem_budget]
+    best = fitting[-1] if fitting else tiles[0]
+    return TileChoice(row_tile=best, working_set_bytes=ws(best),
                       n_grid_steps=h // best)
 
 

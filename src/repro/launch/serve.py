@@ -36,6 +36,7 @@ from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import get_arch, resolve_dtype, with_precision
 from repro.launch import args as largs
+from repro.launch import compile_cache
 from repro.models.lm import Ctx, init_lm
 from repro.serve.cache import PrefixStateCache
 from repro.serve.engine import Request, ServeEngine
@@ -65,6 +66,7 @@ def main():
     largs.add_observability_args(ap)
     args = ap.parse_args()
 
+    compile_cache.enable()
     largs.setup_observability(args)
     largs.load_tune_cache(args, "serve")
 

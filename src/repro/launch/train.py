@@ -20,6 +20,7 @@ import jax
 from repro.configs.base import get_arch, with_precision
 from repro.data.pipeline import DataConfig
 from repro.launch import args as largs
+from repro.launch import compile_cache
 from repro.launch.mesh import (dp_axes_for, make_mesh_for_devices,
                                make_production_mesh)
 from repro.optim.adamw import AdamWConfig
@@ -52,6 +53,7 @@ def main():
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
 
+    compile_cache.enable()
     largs.setup_observability(args)
     largs.load_tune_cache(args, "train")
 

@@ -85,7 +85,7 @@ class LMConfig:
     # GSPN mixer
     gspn_proxy_dim: int = 8
     gspn_row_width: int = 64
-    gspn_impl: str = "xla"         # "sp" shards the folded-grid scans over
+    gspn_impl: str = "auto"        # "sp" shards the folded-grid scans over
     gspn_seq_axis: str = "seq"     # the mesh's seq axis (DESIGN.md §8)
     gspn_sp_strategy: str = "auto"
     # Streamed compute dtype of the GSPN mixer's scans (DESIGN.md §10).

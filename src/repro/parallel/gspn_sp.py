@@ -745,7 +745,7 @@ _sp_pair_core.defvjp(_sp_pair_core_fwd, _sp_pair_core_bwd)
 def gspn_scan_sp(x, wl, wc, wr, lam, *, spec: ScanSpec | None = None,
                  mesh=None, axis_name: str = "seq",
                  strategy: str = "auto", inner_impl: str = "auto",
-                 row_tile: int | None = None, interpret: bool = True,
+                 row_tile: int | None = None, interpret: bool | None = None,
                  chunk: int | None = None, batch_axes=None,
                  boundary_dtype=None, carry_dtype=None,
                  pipeline_depth: int | None = None):
@@ -873,7 +873,8 @@ def _dp_batch_spec(mesh, batch_axes, axis_name, g, gw):
 def gspn_scan_sp_pair(x, wl2, wc2, wr2, lam2, *, spec: ScanSpec | None = None,
                       mesh=None, axis_name: str = "seq",
                       strategy: str = "auto", inner_impl: str = "auto",
-                      row_tile: int | None = None, interpret: bool = True,
+                      row_tile: int | None = None,
+                      interpret: bool | None = None,
                       chunk: int | None = None, batch_axes=None,
                       boundary_dtype=None, carry_dtype=None,
                       pipeline_depth: int | None = None,

@@ -63,17 +63,17 @@ def test_corrupt_or_missing_cache_loads_empty(tmp_path):
 def test_env_cache_layers_over_seed(tmp_path, monkeypatch):
     k = _key(device=A.device_kind(True), h=32)
     extra = A.TuningCache()
-    extra.store(k, {"row_tile": 2, "double_buffer": True, "us": 1.0,
-                    "n_grid_steps": 16, "working_set_bytes": 64,
+    extra.store(k, {"row_tile": 8, "double_buffer": True, "us": 1.0,
+                    "n_grid_steps": 4, "working_set_bytes": 64,
                     "source": "measured"})
     path = extra.save(tmp_path / "overlay.json")
     monkeypatch.setenv(A.ENV_CACHE_PATH, str(path))
     try:
         cache = A.get_cache(reload=True)
-        assert cache.lookup(k)["row_tile"] == 2
+        assert cache.lookup(k)["row_tile"] == 8
         assert A.row_tile_for(32, k.w, c=k.c, direction="fwd",
                               dtype="float32", channel_shared=True,
-                              interpret=True) == 2
+                              interpret=True) == 8     # heuristic: 32
     finally:
         monkeypatch.delenv(A.ENV_CACHE_PATH)
         A.get_cache(reload=True)        # restore the unlayered global
@@ -108,16 +108,16 @@ def test_unknown_device_entry_is_a_miss():
 def test_hit_overrides_heuristic():
     key = _key(device=A.device_kind(False))
     cache = A.TuningCache()
-    cache.store(key, {"row_tile": 2, "double_buffer": True, "us": 1.0,
-                      "n_grid_steps": 32, "working_set_bytes": 64,
+    cache.store(key, {"row_tile": 8, "double_buffer": True, "us": 1.0,
+                      "n_grid_steps": 8, "working_set_bytes": 64,
                       "source": "measured"})
     got = A.row_tile_for(key.h, key.w, c=key.c, direction="fwd",
                          dtype="float32", channel_shared=True, cache=cache)
-    assert got == 2  # not the heuristic's 64
+    assert got == 8  # not the heuristic's 64
 
 
 @pytest.mark.parametrize("bad_entry", [
-    {"row_tile": 3},            # not a power of two
+    {"row_tile": 3},            # not a sublane-tile multiple
     {"row_tile": 48},           # does not divide h=64
     {"row_tile": 0},
     {"row_tile": "wat"},
@@ -237,12 +237,16 @@ def test_warm_measures_real_kernel(tmp_path):
 @pytest.mark.parametrize("budget", [1 << 14, 1 << 16, 1 << 18])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_candidates_never_exceed_vmem_budget(budget, dtype):
+    # Row tiles start at one sublane tile (8 f32 / 16 bf16 rows) rather
+    # than one row, so each budget is scaled by 16 to stay admissible.
+    budget *= 16
     key = _key(h=4096, w=128, dtype=dtype)
     cands = A.enumerate_candidates(key, vmem_budget=budget)
     assert cands, (budget, dtype)
     for c in cands:
-        # the minimal (single-buffered) footprint must fit — admission
-        # may drop prefetch headroom, never the resident working set
+        # the double-buffered footprint Pallas allocates must fit, and so
+        # (a fortiori) must the resident working set
+        assert c.working_set(key) <= budget
         assert A.Candidate(c.row_tile, double_buffer=False) \
             .working_set(key) <= budget
 
@@ -265,14 +269,17 @@ def test_candidate_bf16_carry_byte_accounting():
     k_bf16 = _key(w=w, dtype="bfloat16")
     k_bf16_carry = _key(w=w, dtype="bfloat16", carry_dtype="bfloat16")
     assert A.Candidate(t).working_set(k_f32) == n * t * w * 4 * 2 + w * 4
-    assert A.Candidate(t).working_set(k_bf16) == n * t * w * 2 * 2 + w * 4
+    # narrow streams add a one-group (16-row) f32 widening stage
+    stage = n * 16 * w * 4
+    assert A.Candidate(t).working_set(k_bf16) \
+        == n * t * w * 2 * 2 + stage + w * 4
     assert A.Candidate(t).working_set(k_bf16_carry) \
-        == n * t * w * 2 * 2 + w * 2
+        == n * t * w * 2 * 2 + stage + w * 2
     # adjoint kernels: 5 streams, 3 carry rows, carry always f32
     k_bwd = _key(w=w, direction="bwd", dtype="bfloat16")
     assert k_bwd.carry_bytes == 3 * 4
     assert A.Candidate(t).working_set(k_bwd) \
-        == 5 * t * w * 2 * 2 + w * 12
+        == 5 * t * w * 2 * 2 + 5 * 16 * w * 4 + w * 12
     # at a tight budget (and a scan long enough not to cap on divisors),
     # bf16 streams admit strictly larger tiles
     budget = 1 << 18
@@ -386,12 +393,35 @@ def test_depth_enumeration_follows_stream_width():
     assert A.heuristic_pipeline_depth(_key(dtype="bfloat16")) == 2
 
 
+@pytest.mark.parametrize("g,h,depth", [
+    (8, 2, 2),          # one chunk + boundary row, batch 1
+    (256, 32, 1),       # prefill_32k: batch 32 × 8 planes, 32 rows
+    (2048, 4, 1),       # train_4k: batch 256 × 8 planes, 4 rows
+], ids=["chunk_b1", "prefill_32k", "train_4k"])
+def test_heuristic_plan_fits_vmem_at_model_planes(g, h, depth):
+    """Depth 2 holds all G planes in every grid step, so at the LM
+    shapes' plane counts (G = batch × 8, W = 1024, bf16) the heuristic
+    must fall back to depth 1 rather than emit a plan the compiler
+    refuses; whatever it emits fits the VMEM limit, with or without an
+    explicit row tile."""
+    key = _key(h=h, w=1024, c=g, dtype="bfloat16")
+    assert A.heuristic_pipeline_depth(key) == depth
+    t = A.heuristic_row_tile(key)
+    assert A.Candidate(t, pipeline_depth=depth).working_set(key) \
+        <= tuning.VMEM_BYTES
+    spec = ScanSpec(impl="pallas", channels_per_weight=8,
+                    stream_dtype="bfloat16", row_tile=h)
+    plan = A.plan_for_spec(spec, h, 1024, c=g, cache=A.TuningCache())
+    assert A.Candidate(h, pipeline_depth=plan.pipeline_depth) \
+        .working_set(key) <= tuning.VMEM_BYTES
+
+
 def test_explicit_args_override_plan():
     """An explicit row_tile bypasses the cache; an explicit depth wins
     over both cache and heuristic."""
     key = _key(device=A.device_kind(False), dtype="bfloat16")
     cache = A.TuningCache()
-    cache.store(key, {"row_tile": 4, "pipeline_depth": 1})
+    cache.store(key, {"row_tile": 16, "pipeline_depth": 1})
     kw = dict(c=key.c, direction="fwd", dtype="bfloat16",
               channel_shared=True, cache=cache)
     assert A.plan_for(key.h, key.w, row_tile=32, **kw) \
@@ -399,7 +429,7 @@ def test_explicit_args_override_plan():
     assert A.plan_for(key.h, key.w, row_tile=32, pipeline_depth=1, **kw) \
         == A.ScanPlan(32, 1)
     assert A.plan_for(key.h, key.w, pipeline_depth=2, **kw) \
-        == A.ScanPlan(4, 2)                  # cache tile, forced depth
+        == A.ScanPlan(16, 2)                 # cache tile, forced depth
 
 
 def _scripted_depth(costs):
@@ -454,7 +484,9 @@ def test_scripted_timer_keeps_depth_1_when_faster():
 def test_depth2_candidates_respect_vmem_budget():
     """The staging term is part of admission: at a tight budget the
     largest depth-2 tile is half the largest depth-1 bf16 tile."""
-    key = _key(h=4096, w=128, dtype="bfloat16")
+    # One plane: a depth-2 tile holds all c planes, so c=1 keeps the
+    # comparison per plane.
+    key = _key(h=4096, w=128, dtype="bfloat16", c=1)
     budget = 1 << 18
     cands = A.enumerate_candidates(key, vmem_budget=budget)
     for c in cands:

@@ -114,19 +114,94 @@ def test_chunk_full_equals_unchunked():
 
 
 # ---------------------------------------------------------------------------
+# Scan lengths no sublane tile divides: the whole-H tile (Mosaic's block
+# rule admits only multiples of 8 / 16 rows or the full length).
+# ---------------------------------------------------------------------------
+
+def _check_scan_and_pair(x, wl, wc, wr, lam, want_tile):
+    """gspn_scan and gspn_scan_pair (fwd + grad) at both pipeline depths
+    against the f32 reference on the same (stream-rounded) operands, with
+    the tuner's heuristic tile asserted to be ``want_tile``."""
+    from repro.kernels import autotune
+    from repro.kernels.ops import gspn_scan_pair
+    from repro.kernels.spec import ScanSpec
+
+    f32 = [a.astype(jnp.float32) for a in (x, wl, wc, wr, lam)]
+    tol = 2e-2 if x.dtype == jnp.bfloat16 else 1e-5
+    cpw = x.shape[0] // wl.shape[0]
+    for direction in ("fwd", "pair_fwd"):
+        key = autotune.ScanKey("any", x.shape[1], x.shape[2], x.shape[0],
+                               direction, "pallas", str(x.dtype), "float32",
+                               cpw > 1)
+        for depth in (1, 2):
+            assert autotune.heuristic_row_tile(
+                key, pipeline_depth=depth) == want_tile
+    pair = [jnp.stack([a, a[:, ::-1]]) for a in (wl, wc, wr, lam)]
+    want = R.gspn_scan_ref(*f32)
+    want_rev = R.gspn_scan_ref(*f32[:1], *(a[:, ::-1] for a in f32[1:]),
+                               reverse=True)
+
+    def loss_ref(x, lam):
+        return jnp.sum(jnp.sin(R.gspn_scan_ref(x, *f32[1:4], lam)))
+
+    g_want = jax.grad(loss_ref, argnums=(0, 1))(f32[0], f32[4])
+    for depth in (1, 2):
+        sp = ScanSpec(impl="pallas", pipeline_depth=depth)
+        got = gspn_scan(x, wl, wc, wr, lam, spec=sp)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=tol, atol=tol)
+        got2 = gspn_scan_pair(x, *pair, spec=sp.with_(impl="multidir"))
+        np.testing.assert_allclose(np.asarray(got2[0], np.float32),
+                                   np.asarray(want), rtol=tol, atol=tol)
+        np.testing.assert_allclose(np.asarray(got2[1], np.float32),
+                                   np.asarray(want_rev), rtol=tol, atol=tol)
+
+        def loss(x, lam, sp=sp):
+            h = gspn_scan(x, wl, wc, wr, lam, spec=sp)
+            return jnp.sum(jnp.sin(h.astype(jnp.float32)))
+
+        if x.dtype == jnp.float32:
+            for a, b in zip(jax.grad(loss, argnums=(0, 1))(x, lam), g_want):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("h", [3, 12])
+def test_fold_with_odd_row_count_matches_ref(h, dtype):
+    """An LM prompt folding into 3 or 12 grid rows of width W (planes
+    B·C_proxy with channel-shared taps): no power-of-two multiple of the
+    sublane tile divides H, so the tile is the whole fold."""
+    x, wl, wc, wr, lam = _make(8, h, 64, 2, dtype, seed=h)
+    _check_scan_and_pair(x, wl, wc, wr, lam, want_tile=h)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_narrow_grid_w7_matches_ref(dtype):
+    """The last gspn2-t stage at 224²: a 7×7 grid — whole-H tile and a
+    7-lane row, through the single and the fused pair kernel."""
+    x, wl, wc, wr, lam = _make(4, 7, 7, 2, dtype, seed=7)
+    _check_scan_and_pair(x, wl, wc, wr, lam, want_tile=7)
+
+
+# ---------------------------------------------------------------------------
 # VMEM tile tuner under mixed dtypes (DESIGN.md §10).
 # ---------------------------------------------------------------------------
 
 def test_working_set_math_mixed_dtypes():
     """Exact accounting: n_streams double-buffered streamed tiles in the
-    STREAM dtype + one carry row in the CARRY dtype."""
+    STREAM dtype + one carry row in the CARRY dtype (+ for narrow streams
+    a one-sublane-group f32 widening stage per stream)."""
     t, w, n = 64, 128, 6
     assert tuning.scan_working_set(t, w, 4, n) == n * t * w * 4 * 2 + w * 4
-    # bf16 streams halve only the streamed term; the f32 carry is fixed
-    assert tuning.scan_working_set(t, w, 2, n) == n * t * w * 2 * 2 + w * 4
+    # bf16 streams halve the streamed term and add a 16-row f32 stage;
+    # the f32 carry is fixed
+    stage = n * 16 * w * 4
+    assert tuning.scan_working_set(t, w, 2, n) \
+        == n * t * w * 2 * 2 + stage + w * 4
     # carry_dtype_bytes moves only the carry term
     assert (tuning.scan_working_set(t, w, 2, n, carry_dtype_bytes=2)
-            == n * t * w * 2 * 2 + w * 2)
+            == n * t * w * 2 * 2 + stage + w * 2)
     # headroom: disabling double-buffering halves the streamed term only
     assert (tuning.scan_working_set(t, w, 4, n, double_buffer=False)
             == n * t * w * 4 + w * 4)
@@ -149,7 +224,7 @@ def test_pick_row_tile_bf16_unlocks_double_tile():
 def test_pick_row_tile_carry_bytes_respected():
     """An (artificially) enormous carry must shrink the tile: the carry
     term is part of the budget, not a constant 4-byte afterthought."""
-    budget = 2 ** 16
+    budget = 2 ** 18
     small = tuning.pick_row_tile(1024, 128, 2, vmem_budget=budget)
     big_carry = tuning.pick_row_tile(1024, 128, 2, vmem_budget=budget,
                                      carry_dtype_bytes=400)
@@ -162,7 +237,11 @@ def test_pick_row_tile_carry_bytes_respected():
 def test_pick_row_tile_divides_scan_length(h, dtype_bytes):
     c = tuning.pick_row_tile(h, 64, dtype_bytes, cap=256)
     assert h % c.row_tile == 0
-    assert c.row_tile & (c.row_tile - 1) == 0       # power of two
+    # Mosaic's block rule: a power-of-two multiple of the sublane tile,
+    # or the whole scan length
+    assert c.row_tile == h or (
+        c.row_tile & (c.row_tile - 1) == 0
+        and c.row_tile % tuning.sublane_rows(dtype_bytes) == 0)
     assert c.n_grid_steps == h // c.row_tile
     assert c.row_tile <= 256
 
@@ -180,12 +259,14 @@ def test_bwd_row_tile_sees_streamed_dtype():
 
 
 def test_depth2_staging_term_in_working_set():
-    """Depth-2 adds exactly one f32 staging copy per streamed tile
-    (DESIGN.md §12), independent of the stream dtype."""
+    """Depth-2 keeps exactly one whole-tile f32 staging copy per streamed
+    tile (DESIGN.md §12), independent of the stream dtype; depth 1 stages
+    narrow streams one 16-row group at a time."""
     t, w, n = 64, 128, 6
-    for b in (2, 4):
+    for b, d1_stage in ((2, n * 16 * w * 4), (4, 0)):
         assert (tuning.scan_working_set(t, w, b, n, pipeline_depth=2)
-                == tuning.scan_working_set(t, w, b, n) + n * t * w * 4)
+                == tuning.scan_working_set(t, w, b, n) - d1_stage
+                + n * t * w * 4)
     # bf16 depth-2 footprint lands exactly on the f32 depth-1 footprint
     # (2·2 + 4 = 4·2 bytes per streamed element).
     assert (tuning.scan_working_set(t, w, 2, n, pipeline_depth=2)

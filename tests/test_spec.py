@@ -21,7 +21,9 @@ pytestmark = pytest.mark.kernels
 def test_defaults_and_derived_views():
     sp = ScanSpec()
     assert sp.direction == "fwd" and sp.impl == "auto"
-    assert sp.boundary == "one_shot" and sp.interpret
+    # interpret=None: Mosaic when lowered for a TPU, the interpreter
+    # elsewhere — no default ever forces the interpreter onto the chip.
+    assert sp.boundary == "one_shot" and sp.interpret is None
     assert not sp.channel_shared and sp.channel_mode == "per_channel"
     assert sp.stream_bytes == 4
     assert ScanSpec(channels_per_weight=4).channel_mode == "shared"
@@ -89,7 +91,9 @@ def test_canonical_and_spec_id():
         "fwd", "pallas", "bfloat16", "float32", True, "chunk_resume")
     assert sp.canonical() == \
         "fwd|pallas|bfloat16|carry-float32|cs1|bnd-chunk_resume"
-    assert sp.spec_id() == sp.canonical() + "|cpw3|t8|d2|interp"
+    assert sp.spec_id() == sp.canonical() + "|cpw3|t8|d2|platform"
+    assert sp.with_(interpret=True).spec_id().endswith("|interp")
+    assert sp.with_(interpret=False).spec_id().endswith("|compiled")
     # tile/depth/interpret are launch mechanics, not cache policy.
     assert sp.with_(row_tile=None, pipeline_depth=None).canonical() == \
         sp.canonical()
