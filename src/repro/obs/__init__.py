@@ -7,11 +7,13 @@ One import surface for every instrumented layer::
 
     with obs.trace("serve.decode_step", batch=4) as sp:
         ...
-        sp.set(plan="fwd:t128-d2")
+        sp.set(retired=2)
     obs.counter("serve_decode_steps_total").inc()
 
 Tracing is OFF by default (``obs.enable()`` / ``--trace-out`` turns it
-on; disabled spans are shared no-op singletons).  Metrics are always on.
+on; disabled spans are shared no-op singletons).  Enabled spans also open
+``jax.profiler`` annotations of their name, so a profiler trace shows them
+beside the device's ops.  Metrics are always on.
 Export via :func:`save_chrome_trace` (Perfetto / chrome://tracing) and
 :func:`save_metrics` (JSON or Prometheus text); pretty-print either with
 ``python -m repro.obs.report``.

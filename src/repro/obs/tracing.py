@@ -24,9 +24,11 @@ Span kinds (Chrome trace-event phases, loadable in Perfetto or
 ``chrome://tracing`` via :func:`chrome_trace` / :func:`save_chrome_trace`):
 
 * ``X`` complete spans — ``with trace("serve.decode_step", batch=4) as
-  sp: ...; sp.set(plan=...)``.  When tracing is enabled the span also
-  enters ``jax.named_scope(name)``, so spans wrapping jitted regions
-  line up with XLA's own profiler timeline.
+  sp: ...; sp.set(tokens=...)``.  When tracing is enabled the span also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so under a
+  running ``jax.profiler`` trace it lands in the host plane of the
+  ``.xplane.pb``, on the device's clock (the profiler drops it when no
+  trace is running).
 * ``i`` instant events — ``event("train.loss_scale", scale=2048.0)``.
 * ``b``/``e`` async spans — ``async_begin("request", uid)`` /
   ``async_end("request", uid)``: long-lived logical operations (a serve
@@ -48,9 +50,9 @@ monotonic = time.perf_counter
 monotonic_ns = time.perf_counter_ns
 
 try:  # tracing works without jax (the subsystem is dependency-free)
-    from jax import named_scope as _named_scope
-except Exception:  # pragma: no cover - jax is always present in this repo
-    _named_scope = None
+    from jax.profiler import TraceAnnotation as _Annotation
+except ImportError:  # pragma: no cover - jax is always present in this repo
+    _Annotation = None
 
 DEFAULT_RING = 65536
 
@@ -95,10 +97,9 @@ class Record:
 
 class Span:
     """A live ``X`` span.  ``set(**attrs)`` annotates it after creation —
-    the idiom for attributes only known mid-span (the resolved kernel
-    plan of a decode step)."""
+    the idiom for attributes only known mid-span."""
 
-    __slots__ = ("name", "args", "_t0", "_tid", "_scope")
+    __slots__ = ("name", "args", "_t0", "_tid", "_note")
 
     def __init__(self, name, args):
         self.name = name
@@ -109,19 +110,19 @@ class Span:
         return self
 
     def __enter__(self):
-        self._scope = None
-        if _named_scope is not None:
-            # line our spans up with XLA's profiler timeline
-            self._scope = _named_scope(self.name)
-            self._scope.__enter__()
+        self._note = None
+        if _Annotation is not None:
+            # the same span on the profiler's clock, beside the device ops
+            self._note = _Annotation(self.name)
+            self._note.__enter__()
         self._tid = threading.get_ident()
         self._t0 = monotonic_ns()
         return self
 
     def __exit__(self, et, ev, tb):
         t1 = monotonic_ns()
-        if self._scope is not None:
-            self._scope.__exit__(et, ev, tb)
+        if self._note is not None:
+            self._note.__exit__(et, ev, tb)
         _BUF.append(Record("X", self.name, self._t0, t1 - self._t0,
                            self._tid, None, self.args))
         return False

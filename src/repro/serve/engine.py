@@ -31,9 +31,13 @@ engine-level counters and latency histograms in the process-global
 kept as a per-engine compat view (the historical dict keys plus a derived
 ``queue_depth_mean``).  With tracing enabled the engine emits the request
 lifecycle as spans: one async ``request`` span per uid
-(queued → admitted → finished) enclosing the engine thread's
-``serve.prefill_chunk`` / ``serve.decode_step`` child spans, the latter
-annotated with the autotuner-resolved kernel plan.
+(queued → admitted → finished) enclosing the engine thread's spans.  Each
+``serve.tick`` holds one span per phase (``serve.admit``,
+``serve.cache_init``, ``serve.prefill``, ``serve.first_token``,
+``serve.cache_commit``, ``serve.prefill_chunk`` with its
+``serve.prefill_sync``, ``serve.decode_step`` with its
+``serve.token_read`` and ``serve.emit``), never one per slot; under a
+``jax.profiler`` trace they land beside the device's ops.
 
 Submission surface: ``submit`` returns a :class:`RequestHandle`
 (uid / status / ``result()`` accessor) — the one object a caller, the
@@ -101,14 +105,6 @@ def _serve_metrics():
                                  help="wall seconds per prefill chunk "
                                       "(the TTFT predictor's cost model)"),
     }
-
-
-def _kernel_plan_summary() -> str:
-    """Compact string of every (row_tile, pipeline_depth) plan the
-    autotuner has resolved in this process — the decode-step span
-    annotation (DESIGN.md §11/§13)."""
-    from repro.kernels import autotune
-    return autotune.plans_summary()
 
 
 def sample_tokens(logits, rng, temperature: float, top_k: int):
@@ -370,15 +366,17 @@ class ServeEngine:
             i = 0
         return self.waiting.pop(i)
 
-    def _sample_first(self, logits_row):
-        """Draw a request's first token (from the last prefill logits)
-        under the SAME policy as decode (sample_tokens)."""
-        if self.temperature <= 0.0:
-            sub = self.rng                   # unused; keep the stream fixed
-        else:
-            self.rng, sub = jax.random.split(self.rng)
-        return int(sample_tokens(logits_row[None], sub,
-                                 self.temperature, self.top_k)[0])
+    def _sample_first(self, logits):
+        """Draw a request's first token from the last position of its
+        prefill logits (1, S, V) under the SAME policy as decode
+        (sample_tokens), and read it to the host."""
+        with obs.trace("serve.first_token"):
+            if self.temperature <= 0.0:
+                sub = self.rng               # unused; keep the stream fixed
+            else:
+                self.rng, sub = jax.random.split(self.rng)
+            return int(sample_tokens(logits[:, -1], sub,
+                                     self.temperature, self.top_k)[0])
 
     @property
     def idle(self) -> bool:
@@ -468,7 +466,9 @@ class ServeEngine:
                     # scratch would need leaf-selective resets — a stale
                     # GSPN prev_row corrupts the seeded scan — for one
                     # saved zero-fill; not worth the foot-gun.
-                    cache = lm_mod.init_lm_cache(self.cfg, 1, self.max_len)
+                    with obs.trace("serve.cache_init"):
+                        cache = lm_mod.init_lm_cache(self.cfg, 1,
+                                                     self.max_len)
                 self._inflight = {
                     "req": req, "slot": slot, "off": off, "chunks": 0,
                     "cached": off, "toks": toks, "cache": cache,
@@ -479,9 +479,9 @@ class ServeEngine:
                                prompt_tokens=len(req.prompt)):
                     prompt = jnp.asarray(req.prompt, jnp.int32)[None]
                     logits, new_caches = self._prefill(self.params, prompt)
-                    first = self._sample_first(logits[0, -1])
-                    self.pool.commit(slot, new_caches)
-                self._activate(req, slot, first, t_submit, t_admit, 0)
+                first = self._sample_first(logits)
+                self._activate(req, slot, first, new_caches, t_submit,
+                               t_admit, 0)
 
     def _advance_prefill(self):
         """Run at most one prompt chunk of the in-flight prefill."""
@@ -504,7 +504,8 @@ class ServeEngine:
             # dispatch: the per-chunk latency is the TTFT predictor's
             # cost model (DESIGN.md §15), and the very next tick would
             # block on this state anyway.
-            jax.block_until_ready(st["cache"])
+            with obs.trace("serve.prefill_sync"):
+                jax.block_until_ready(st["cache"])
         _serve_metrics()["chunk_s"].observe(obs.monotonic() - t0)
         st["off"] = end
         st["chunks"] += 1
@@ -517,15 +518,19 @@ class ServeEngine:
             # a GSPN fold-row boundary (the resumable-state contract).
             self.prefix_cache.insert(st["toks"][:end], st["cache"])
         if last:
-            first = self._sample_first(logits[0, -1])
-            self.pool.commit(st["slot"], st["cache"])
-            self._activate(st["req"], st["slot"], first,
+            first = self._sample_first(logits)
+            self._activate(st["req"], st["slot"], first, st["cache"],
                            st["t_submit"], st["t_admit"], st["chunks"],
                            cached=st["cached"])
             self._inflight = None
 
-    def _activate(self, req, slot, first, t_submit, t_admit, chunks,
+    def _activate(self, req, slot, first, cache, t_submit, t_admit, chunks,
                   cached: int = 0):
+        """Commit a finished prefill's ``cache`` to ``slot`` and start
+        decoding the request from its ``first`` token."""
+        with obs.trace("serve.cache_commit"):
+            self.pool.commit(slot, cache)
+            self.last_token = self.last_token.at[slot, 0].set(first)
         now = obs.monotonic()
         res = Result(uid=req.uid, tokens=[first], ttft=now - t_submit,
                      queue_delay=t_admit - t_submit, prefill_chunks=chunks,
@@ -541,7 +546,6 @@ class ServeEngine:
         self.slot_req[slot] = req
         self._slot_res[slot] = res
         self._slot_t_last[slot] = now
-        self.last_token = self.last_token.at[slot, 0].set(first)
         self.active[slot] = True
         if self.stream:
             self.stream(req.uid, first)
@@ -580,37 +584,34 @@ class ServeEngine:
     def _decode_step(self):
         """One decode step for the whole batch."""
         sm = _serve_metrics()
-        with obs.trace("serve.decode_step",
-                       batch=int(self.active.sum())) as sp:
+        with obs.trace("serve.decode_step", batch=int(self.active.sum())):
             self.rng, sub = jax.random.split(self.rng)
             nxt, new_caches = self._decode(self.params, self.last_token,
                                            self.pool.caches, sub)
             self.pool.update(new_caches)
             self._m["decode_steps"] += 1
             sm["decode"].inc()
-            nxt_host = np.asarray(nxt)
-            if obs.enabled():
-                # annotate with the autotuner-resolved (row_tile, depth)
-                # plans the launches inside this step funnelled through
-                sp.set(plan=_kernel_plan_summary())
+            with obs.trace("serve.token_read"):
+                nxt_host = np.asarray(nxt)
             self.last_token = nxt[:, None]
             now = obs.monotonic()
-            for slot in range(self.bs):
-                if not self.active[slot]:
-                    continue
-                tok = int(nxt_host[slot])
-                res = self._slot_res[slot]
-                res.tokens.append(tok)
-                res.itl.append(now - self._slot_t_last[slot])
-                sm["itl"].observe(now - self._slot_t_last[slot])
-                self._slot_t_last[slot] = now
-                if self.stream:
-                    self.stream(res.uid, tok)
-                req = self.slot_req[slot]
-                if self.eos_id is not None and tok == self.eos_id:
-                    self._retire(slot, "eos")
-                elif len(res.tokens) >= req.max_new_tokens:
-                    self._retire(slot, "length")
+            with obs.trace("serve.emit"):
+                for slot in range(self.bs):
+                    if not self.active[slot]:
+                        continue
+                    tok = int(nxt_host[slot])
+                    res = self._slot_res[slot]
+                    res.tokens.append(tok)
+                    res.itl.append(now - self._slot_t_last[slot])
+                    sm["itl"].observe(now - self._slot_t_last[slot])
+                    self._slot_t_last[slot] = now
+                    if self.stream:
+                        self.stream(res.uid, tok)
+                    req = self.slot_req[slot]
+                    if self.eos_id is not None and tok == self.eos_id:
+                        self._retire(slot, "eos")
+                    elif len(res.tokens) >= req.max_new_tokens:
+                        self._retire(slot, "length")
 
     # -- main loop ----------------------------------------------------------
     def tick(self):
@@ -620,7 +621,8 @@ class ServeEngine:
             sm = _serve_metrics()
             self._m["ticks"] += 1
             sm["ticks"].inc()
-            self._admit()
+            with obs.trace("serve.admit"):
+                self._admit()
             # Depth is sampled AFTER admission: requests that found a free
             # slot this very tick never waited it out, so counting them
             # (the old pre-admit sample) double-counted depth on every

@@ -60,6 +60,40 @@ def test_disabled_trace_is_a_shared_noop_singleton():
     assert obs.trace("a") is not obs.NOOP_SPAN
 
 
+def _host_names(xplane_dir) -> set:
+    """Event names on the host plane of the one ``.xplane.pb`` under
+    ``xplane_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = pathlib.Path(xplane_dir).glob("**/*.xplane.pb")
+    planes = ProfileData.from_file(str(path)).planes
+    return {e.name for p in planes if p.name == "/host:CPU"
+            for line in p.lines for e in line.events}
+
+
+def test_enabled_spans_land_in_the_profilers_host_plane(tmp_path):
+    """An enabled span opens a profiler annotation of exactly its name;
+    a disabled one is the no-op singleton and reaches no trace."""
+    import time
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        time.sleep(0.05)                # the tracer drops spans opened at once
+        obs.enable()
+        with obs.trace("serve.tick", uid=3):
+            with obs.trace("serve.emit"):
+                jnp.ones(4).block_until_ready()
+        obs.disable()
+        assert obs.trace("serve.admit") is obs.NOOP_SPAN
+        with obs.trace("serve.admit"):
+            pass
+        time.sleep(0.05)
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_names(tmp_path)
+    assert {"serve.tick", "serve.emit"} <= names
+    assert "serve.admit" not in names
+    assert [r.name for r in obs.spans()] == ["serve.emit", "serve.tick"]
+
+
 def test_span_records_duration_and_late_attrs():
     obs.enable()
     with obs.trace("phase", size=3) as sp:
@@ -263,7 +297,7 @@ def test_engine_emits_request_to_kernel_spans(engine):
     assert all(c.args["uid"] == 0 for c in chunks)
     steps = obs.spans("serve.decode_step")
     assert steps, "no decode-step spans recorded"
-    assert all("plan" in s.args and "batch" in s.args for s in steps)
+    assert all("batch" in s.args for s in steps)
 
     m = engine.metrics                  # compat view + derived mean
     assert m["decode_steps"] == len(steps)
@@ -275,6 +309,80 @@ def test_engine_emits_request_to_kernel_spans(engine):
     assert snap["counters"]["serve_requests_finished_total"] == 3
     assert snap["counters"]["serve_decode_steps_total"] == len(steps)
     assert snap["histograms"]["serve_ttft_seconds"]["count"] == 3
+
+
+# Each tick phase and the span that must enclose it on the same thread.
+TICK_PHASES = {"serve.admit": "serve.tick", "serve.cache_init": "serve.admit",
+               "serve.prefill": "serve.admit",
+               "serve.first_token": "serve.tick",
+               "serve.cache_commit": "serve.tick",
+               "serve.prefill_chunk": "serve.tick",
+               "serve.prefill_sync": "serve.prefill_chunk",
+               "serve.decode_step": "serve.tick",
+               "serve.token_read": "serve.decode_step",
+               "serve.emit": "serve.decode_step"}
+
+
+def _run_mixed(engine):
+    """One chunked prompt (24 > chunk 8), two one-shot prompts, one of
+    which waits for a slot; returns the recorded spans."""
+    from repro.serve.engine import Request
+    engine.reset()
+    obs.enable()
+    engine.submit(Request(uid=0, prompt=np.arange(24) % 64,
+                          max_new_tokens=4))
+    engine.submit(Request(uid=1, prompt=np.arange(6) % 64, max_new_tokens=3))
+    engine.submit(Request(uid=2, prompt=np.arange(6) % 64, max_new_tokens=3))
+    engine.run()
+    obs.disable()
+    return obs.spans()
+
+
+def test_engine_tick_phases_nest_in_their_tick(engine):
+    spans = _run_mixed(engine)
+    assert {s.name for s in spans} == set(TICK_PHASES) | {"serve.tick"}
+
+    def within(s, o):
+        return (o is not s and o.tid == s.tid and o.ts <= s.ts
+                and s.ts + s.dur <= o.ts + o.dur)
+
+    ticks = [s for s in spans if s.name == "serve.tick"]
+    assert len(ticks) == engine.metrics["ticks"]
+    for s in spans:
+        if s.name in TICK_PHASES:
+            assert any(within(s, o) for o in spans
+                       if o.name == TICK_PHASES[s.name]), s.name
+            assert any(within(s, t) for t in ticks), s.name
+    # one span per phase and tick, never one per slot
+    for t in ticks:
+        names = [s.name for s in spans if within(s, t)]
+        for once in ("serve.admit", "serve.decode_step", "serve.token_read",
+                     "serve.emit", "serve.prefill_chunk"):
+            assert names.count(once) <= 1, (once, names)
+        assert names.count("serve.admit") == 1
+    # every admission samples one first token and commits one cache
+    assert len([s for s in spans if s.name == "serve.first_token"]) == 3
+    assert len([s for s in spans if s.name == "serve.cache_commit"]) == 3
+    assert len([s for s in spans if s.name == "serve.cache_init"]) == 1
+
+
+def test_disabled_engine_makes_no_span_or_annotation(engine, monkeypatch):
+    """With tracing off the instrumentation creates nothing beyond the
+    shared no-op singleton: no Span and no profiler annotation."""
+    from repro.obs import tracing
+    from repro.serve.engine import Request
+
+    def refuse(*a, **k):
+        raise AssertionError("disabled tracing created an object")
+
+    monkeypatch.setattr(tracing, "Span", refuse)
+    monkeypatch.setattr(tracing, "_Annotation", refuse)
+    engine.reset()
+    engine.submit(Request(uid=0, prompt=np.arange(24) % 64,
+                          max_new_tokens=3))
+    engine.submit(Request(uid=1, prompt=np.arange(6) % 64, max_new_tokens=3))
+    assert sorted(engine.run()) == [0, 1]
+    assert obs.records() == []
 
 
 def test_queue_depth_not_counted_on_admission_tick(engine):
@@ -321,7 +429,15 @@ def test_disabled_tracing_overhead_under_2pct_of_decode_step(engine):
             obs.event("y", z=3)
         best = min(best, obs.monotonic() - t0)
     per_call = best / (2 * n)
-    calls_per_step = 16                 # actual instrumented calls/tick ~6
+    # every span, event and async record one tick can make (the mixed
+    # run: admissions, chunks, first tokens, decode, retirements) fits
+    calls_per_step = 32
+    spans = _run_mixed(engine)
+    engine.reset()
+    ticks = [(s.ts, s.ts + s.dur) for s in spans if s.name == "serve.tick"]
+    recs = obs.records()
+    most = max(sum(1 for r in recs if a <= r.ts <= b) for a, b in ticks)
+    assert most <= calls_per_step, most
     assert per_call * calls_per_step < 0.02 * step_s, (
         f"disabled-tracing overhead {per_call * calls_per_step * 1e6:.2f}us "
         f"vs 2% of decode step {0.02 * step_s * 1e6:.2f}us")
