@@ -75,13 +75,10 @@ DIRECTIONS = ("fwd", "bwd", "pair_fwd", "pair_bwd", "quad")
 _N_STREAMS = {"fwd": 6, "bwd": 5, "pair_fwd": 6, "pair_bwd": 5, "quad": 6}
 _CARRY_ROWS = {"fwd": 1, "bwd": 3, "pair_fwd": 1, "pair_bwd": 3, "quad": 1}
 
-# Pipeline depths the kernels implement (DESIGN.md §12).  Depth 2 (the
-# explicitly staged pipeline) is only ever ENUMERATED for narrow streams
-# (< 4 bytes): the stage exists to amortise the narrow-dtype widen-on-load
-# and sublane retiling over a whole tile, and for f32 streams it is a dead
-# VMEM copy that doubles residency for nothing.  The kernels themselves
-# accept depth 2 at any dtype (the conformance grid proves both depths
-# bit-identical) — the restriction is admission policy, not capability.
+# Pipeline depths the kernels implement (DESIGN.md §12).  The kernels
+# accept either depth at any dtype (the conformance grid proves them
+# bit-identical); which depth a key may run is admission policy
+# (``depth_admissible``).
 PIPELINE_DEPTHS = (1, 2)
 
 # Injectable timer — tests monkeypatch this (or pass ``timer=``) to make
@@ -102,6 +99,8 @@ def _record_plan(key: "ScanKey", plan: "ScanPlan", source: str):
     prev = _RESOLVED.get(key.encode())
     _RESOLVED[key.encode()] = (plan.row_tile, plan.pipeline_depth, source)
     if prev is None:
+        obs.counter(f"autotune_plans_depth{plan.pipeline_depth}_total",
+                    "launch keys resolved to this pipeline depth").inc()
         obs.event("kernel.plan", key=key.encode(), row_tile=plan.row_tile,
                   pipeline_depth=plan.pipeline_depth, source=source)
 
@@ -220,28 +219,45 @@ class ScanPlan:
     pipeline_depth: int = 1
 
 
+def _staged_fits(key: ScanKey, row_tile: int | None = None) -> bool:
+    """Whether the depth-2 working set (all G planes resident) of
+    ``row_tile``, else of the smallest admissible tile, fits the VMEM
+    limit."""
+    t = row_tile or tuning.admissible_tiles(key.h, key.stream_bytes,
+                                            DEFAULT_CAP)[0]
+    return Candidate(t, pipeline_depth=2).working_set(key) \
+        <= tuning.VMEM_BYTES
+
+
 def depth_admissible(key: ScanKey, pipeline_depth: int) -> bool:
-    """Admission policy for the staged pipeline: depth 2 only pays for
-    narrow (< 4-byte) streams — see PIPELINE_DEPTHS."""
+    """Admission policy for the staged pipeline (DESIGN.md §12).  Depth 1
+    always.  Depth 2 for narrow (< 4-byte) streams, whose widen-on-load
+    it amortises over a tile; and for 4-byte streams on a compiled device
+    (not ``+interpret``: the interpreter has no sublanes to fill) when
+    the G planes fill a vreg's sublanes (G >= 8) and a depth-2 tile fits
+    VMEM — there one row step advances every plane at once instead of
+    one plane's row, so the row recurrence's latency is paid H times per
+    launch rather than G·H times."""
     if pipeline_depth == 1:
         return True
-    return pipeline_depth == 2 and key.stream_bytes < 4
+    if pipeline_depth != 2:
+        return False
+    if key.stream_bytes < 4:
+        return True
+    return (not key.device.endswith("+interpret")
+            and key.c >= tuning.sublane_rows(key.stream_bytes)
+            and _staged_fits(key))
 
 
 def heuristic_pipeline_depth(key: ScanKey, *,
                              row_tile: int | None = None) -> int:
-    """Static-fallback depth: the staged pipeline for narrow streams
-    (bf16/fp8) when its tile (``row_tile``, else the smallest admissible
-    one) fits the VMEM budget with all G planes resident; otherwise the
-    classic one-plane-per-step stream, which fits at any G and is what
-    full-width f32 always runs."""
-    if not depth_admissible(key, 2):
-        return 1
-    t = row_tile or tuning.admissible_tiles(key.h, key.stream_bytes,
-                                            DEFAULT_CAP)[0]
-    fits = Candidate(t, pipeline_depth=2).working_set(key) \
-        <= tuning.VMEM_BYTES
-    return 2 if fits else 1
+    """Static-fallback depth: the staged pipeline wherever it is
+    admissible (``depth_admissible``) and its tile (``row_tile``, else the
+    smallest admissible one) fits the VMEM budget with all G planes
+    resident; otherwise the classic one-plane-per-step stream, which fits
+    at any G."""
+    return 2 if depth_admissible(key, 2) and _staged_fits(key, row_tile) \
+        else 1
 
 
 def enumerate_candidates(key: ScanKey, *,
@@ -253,7 +269,7 @@ def enumerate_candidates(key: ScanKey, *,
     or the whole length) whose double-buffered working set fits the VMEM
     budget — Pallas always double-buffers its blocks, so a tile that fits
     only single-buffered is refused by the compiler — at every admissible
-    pipeline depth (depth 2 only for narrow streams)."""
+    pipeline depth (``depth_admissible``)."""
     out: list[Candidate] = []
     for t in tuning.admissible_tiles(key.h, key.stream_bytes, cap):
         for depth in PIPELINE_DEPTHS:

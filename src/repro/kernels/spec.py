@@ -183,8 +183,9 @@ def enumerate_specs(*, boundaries=("one_shot",),
       the aggressive stream-width carry for narrow streams;
     * channel mode per-channel (cpw=1) and compact (cpw>1);
     * pipeline depth 1 and 2 for the fused kernels (the kernels accept
-      depth 2 at any dtype — the tuner's narrow-stream restriction is
-      admission policy, not capability), None for xla (no pipeline);
+      depth 2 at any dtype — which keys the tuner stages is admission
+      policy, ``autotune.depth_admissible``, not capability), None for
+      xla (no pipeline);
     * every requested boundary behaviour (numerics are boundary-label
       invariant; the label keys the cache and the routing).
 

@@ -380,17 +380,25 @@ def test_garbage_pipeline_depth_entry_falls_back():
         assert plan.pipeline_depth == 1
 
 
-def test_depth_enumeration_follows_stream_width():
-    """Depth 2 is enumerated only for narrow (< 4-byte) streams; depth 1
-    is always present."""
-    depths_f32 = {c.pipeline_depth
-                  for c in A.enumerate_candidates(_key())}
-    depths_bf16 = {c.pipeline_depth
-                   for c in A.enumerate_candidates(_key(dtype="bfloat16"))}
-    assert depths_f32 == {1}
-    assert depths_bf16 == {1, 2}
-    assert A.heuristic_pipeline_depth(_key()) == 1
-    assert A.heuristic_pipeline_depth(_key(dtype="bfloat16")) == 2
+@pytest.mark.parametrize("kw,depths,heuristic", [
+    ({}, {1}, 1),
+    (dict(device="cpu+interpret", h=56, w=56, c=64), {1}, 1),
+    (dict(dtype="bfloat16"), {1, 2}, 2),
+    (dict(device="tpu-v5-lite", h=56, w=56, c=64), {1, 2}, 2),
+    (dict(device="tpu-v5-lite", h=256, w=256, c=16), {1, 2}, 2),
+    (dict(device="tpu-v5-lite", c=4), {1}, 1),
+    (dict(device="tpu-v5-lite", h=4, w=1024, c=2048), {1}, 1),
+], ids=["f32-g4", "f32-interpret", "bf16", "f32-v5e-g64", "f32-v5e-g16",
+        "f32-v5e-g4", "f32-v5e-too-big"])
+def test_depth_enumeration_follows_stream_width(kw, depths, heuristic):
+    """Depth 1 is always enumerated.  Depth 2 is enumerated for narrow
+    (< 4-byte) streams, and for 4-byte streams only on a compiled device
+    (not ``+interpret``) with G >= 8 planes whose depth-2 working set
+    fits VMEM: the train_224 (G 64, W = H = 56) and infer_1024 (G 16,
+    W = H = 256) stages, not G 4, nor G 2048 of 1024-wide rows."""
+    key = _key(**kw)
+    assert {c.pipeline_depth for c in A.enumerate_candidates(key)} == depths
+    assert A.heuristic_pipeline_depth(key) == heuristic
 
 
 @pytest.mark.parametrize("g,h,depth", [

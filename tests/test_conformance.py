@@ -371,7 +371,8 @@ def test_every_tuner_candidate_matches_oracle(sp):
     # winner can therefore never be slower than the heuristic beyond
     # timing noise (the tuner times the heuristic tile too).
     assert autotune.heuristic_row_tile(key) in tiles
-    # Depth 2 is enumerated exactly for narrow streams (admission policy).
+    # In interpret mode depth 2 is enumerated exactly for narrow streams
+    # (admission policy: f32 stages only on a compiled device).
     assert (2 in {d for _, d in plans}) == (key.stream_bytes < 4)
 
     if sp.direction == "pair_fwd":
@@ -415,27 +416,39 @@ def test_every_tuner_candidate_matches_oracle(sp):
 # element — staging only changes where casts and copies happen, never the
 # arithmetic.  In interpret mode that makes the two depths bit-identical,
 # and this grid pins it: forward AND grad, all four directions, the fused
-# pair, the quad launch, bf16/f32 streams, bf16/f32 carries.
+# pair, the quad launch, bf16/f32 streams, bf16/f32 carries — two row
+# tiles of 4 planes sharing one weight plane (taps broadcast in the
+# stage) — plus the stage gspn2-t's train step runs at 224²: f32 pair,
+# two planes per weight plane, 8 weight planes (taps staged at their own
+# planes, data planes channel-major), one whole 14-row tile.
 # ---------------------------------------------------------------------------
 
 DEPTH_DIRS = SINGLE_DIRS + ["pair", "quad"]
 DTYPES = ["float32", "bfloat16"]
+# (H = W, planes, weight planes, row tile)
+TWO_TILES = (16, 4, 1, 8)
+UNALIGNED_CPW2 = (14, 16, 8, 14)
+DEPTH_CASES = [
+    pytest.param(d, dt, cd, TWO_TILES, id=f"{d}-{dt}-{cd}")
+    for d in DEPTH_DIRS for dt in DTYPES for cd in ["float32", "bfloat16"]
+] + [pytest.param("pair", "float32", "float32", UNALIGNED_CPW2,
+                  id="pair-float32-float32-cpw2-h14")]
 
 
-@pytest.mark.parametrize("carry_dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("direction", DEPTH_DIRS)
-def test_pipeline_depth_bit_agreement(direction, dtype, carry_dtype):
+@pytest.mark.parametrize("direction,dtype,carry_dtype,geometry",
+                         DEPTH_CASES)
+def test_pipeline_depth_bit_agreement(direction, dtype, carry_dtype,
+                                      geometry):
     seed = 77 + DEPTH_DIRS.index(direction)
-    h = w = 16
-    c, gw = 4, 1
+    h, c, gw, row_tile = geometry
+    w = h
 
     def bitwise(a, b):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
 
     def spec_at(depth, **kw):
-        return ScanSpec(channels_per_weight=c, row_tile=8,
+        return ScanSpec(channels_per_weight=c // gw, row_tile=row_tile,
                         carry_dtype=carry_dtype, pipeline_depth=depth,
                         **kw)
 

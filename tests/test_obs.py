@@ -249,6 +249,27 @@ def test_plan_resolutions_are_recorded_once_and_summarised():
     assert f"t{plan.row_tile}-d{plan.pipeline_depth}" in s
 
 
+def test_plan_depth_counters_count_each_launch_key_once():
+    """``autotune_plans_depth{1,2}_total`` count launch keys by the depth
+    they resolve to: an f32 pair plan on a compiled device at G 64 (the
+    staged f32 path) and the same key's bf16 plan increment the depth-2
+    counter once each, a key resolved again increments nothing, and the
+    f32 plan in interpret mode lands on depth 1."""
+    cache = autotune.TuningCache()
+    f32 = ScanSpec(direction="pair_fwd", impl="multidir",
+                   channels_per_weight=2, interpret=False)
+    bf16 = f32.with_(stream_dtype="bfloat16")
+    for spec in (f32, bf16, f32):
+        assert autotune.plan_for_spec(spec, 56, 56, c=64,
+                                      cache=cache).pipeline_depth == 2
+    assert obs.REGISTRY.get("autotune_plans_depth2_total").value == 2
+    assert obs.REGISTRY.get("autotune_plans_depth1_total") is None
+    autotune.plan_for_spec(f32.with_(interpret=True), 56, 56, c=64,
+                           cache=cache)
+    assert obs.REGISTRY.get("autotune_plans_depth1_total").value == 1
+    assert obs.REGISTRY.get("autotune_plans_depth2_total").value == 2
+
+
 # ---------------------------------------------------------------------------
 # Serve-engine instrumentation (the ISSUE acceptance shape).
 # ---------------------------------------------------------------------------

@@ -16,7 +16,12 @@ fused pair, f32 and bf16 streams, and assert a Mosaic kernel
   where depth 2 cannot hold every plane in VMEM and the tuner must emit
   depth 1;
 * the gspn2-t stages at 224², batch 8 (G = 16, 8 weight planes):
-  W = H ∈ {56, 7}.
+  W = H ∈ {56, 7};
+* the gspn2-t stages the benchmark's vision cells run, fused pair
+  forward and adjoint in f32: train_224 (batch 32, G = 64) at W = H ∈
+  {56, 28, 14, 7} and infer_1024 (batch 8, G = 16) at W = H ∈ {256, 128,
+  64, 32} — the plan the heuristic picks, which is the plan they run
+  (every enumerated plan there would add minutes of compiles).
 
 The topology is described inside a module fixture, never while a module
 is imported: only the worker that runs this file loads the TPU library.
@@ -45,6 +50,8 @@ LM_SHAPES = [(32, 1, 1024, 8), (32, 2, 1024, 8), (32, 3, 1024, 8),
              (32, 64, 1024, 8), (32, 1024, 1, 8), (32, 1024, 3, 8),
              (256, 32, 1024, 8)]
 VISION_SHAPES = [(16, 56, 56, 2), (16, 7, 7, 2)]
+CELL_STAGE_SHAPES = ([(64, s, s, 2) for s in (56, 28, 14, 7)]
+                     + [(16, s, s, 2) for s in (256, 128, 64, 32)])
 KINDS = {"fwd": ("fwd", "pallas"), "bwd": ("bwd", "pallas"),
          "pair": ("pair_fwd", "multidir"), "pair_grad": ("pair_bwd",
                                                          "multidir")}
@@ -83,18 +90,10 @@ def _emitted_plans(key):
     return sorted(plans)
 
 
-@pytest.mark.parametrize("kind", list(KINDS))
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("shape", LM_SHAPES + VISION_SHAPES,
-                         ids=lambda s: "g{}h{}w{}cpw{}".format(*s))
-def test_every_emitted_plan_compiles(one_chip, shape, dtype, kind):
+def _compile_plans(one_chip, shape, dtype, kind, plans):
     g, h, w, cpw = shape
     gw = g // cpw
-    direction, impl = KINDS[kind]
-    key = A.ScanKey("tpu-v5-lite", h, w, g, direction, impl, dtype,
-                    "float32", cpw > 1)
-    plans = _emitted_plans(key)
-    assert plans
+    _, impl = KINDS[kind]
 
     def sds(*s):
         return jax.ShapeDtypeStruct(s, jnp.dtype(dtype), sharding=one_chip)
@@ -123,6 +122,35 @@ def test_every_emitted_plan_compiles(one_chip, shape, dtype, kind):
                 dy, a, b, c, spec=sp)
             args = [data, *taps]
         assert "tpu_custom_call" in _compiled_text(fn, args), (t, d)
+
+
+def _key(shape, dtype, kind):
+    g, h, w, cpw = shape
+    direction, impl = KINDS[kind]
+    return A.ScanKey("tpu-v5-lite", h, w, g, direction, impl, dtype,
+                     "float32", cpw > 1)
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", LM_SHAPES + VISION_SHAPES,
+                         ids=lambda s: "g{}h{}w{}cpw{}".format(*s))
+def test_every_emitted_plan_compiles(one_chip, shape, dtype, kind):
+    plans = _emitted_plans(_key(shape, dtype, kind))
+    assert plans
+    _compile_plans(one_chip, shape, dtype, kind, plans)
+
+
+@pytest.mark.parametrize("kind", ["pair", "pair_grad"])
+@pytest.mark.parametrize("shape", CELL_STAGE_SHAPES,
+                         ids=lambda s: "g{}h{}w{}cpw{}".format(*s))
+def test_cell_stage_plan_compiles(one_chip, shape, kind):
+    """The vision cells' f32 stages take the staged (depth-2) plan on a
+    v5e, and it compiles."""
+    key = _key(shape, "float32", kind)
+    plan = (A.heuristic_row_tile(key), A.heuristic_pipeline_depth(key))
+    assert plan[1] == 2
+    _compile_plans(one_chip, shape, "float32", kind, [plan])
 
 
 @pytest.mark.parametrize("pair", [False, True], ids=["single", "pair"])
