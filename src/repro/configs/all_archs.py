@@ -15,10 +15,13 @@ import repro.configs.whisper_base     # noqa: F401
 # Beyond-paper GSPN-mixer variant (this work).
 import repro.configs.qwen2_1_5b_gspn  # noqa: F401
 
+# Mamba-2 + attention hybrid served beside it (two kinds of cache state).
+import repro.configs.granite_4_0_h_micro  # noqa: F401
+
 ASSIGNED = [
     "xlstm-1.3b", "qwen1.5-32b", "granite-3-2b", "qwen2-1.5b",
     "qwen2.5-3b", "zamba2-2.7b", "qwen2-vl-72b", "kimi-k2-1t-a32b",
     "grok-1-314b", "whisper-base",
 ]
 
-EXTRAS = ["qwen2-1.5b-gspn"]
+EXTRAS = ["qwen2-1.5b-gspn", "granite-4.0-h-micro"]

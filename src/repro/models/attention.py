@@ -202,24 +202,34 @@ def chunked_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
     return _flash_attention(q, k, v, causal, block_k, q_offset)
 
 
-def chunk_prefill_attention(q, k_cache, v_cache, q_offset):
+def chunk_prefill_attention(q, k_cache, v_cache, q_offset,
+                            block_k: int = 512):
     """Chunked-prefill attention (DESIGN.md §9): a T-token prompt chunk at
     absolute offset ``q_offset`` (traced scalar ok) attends over the padded
     KV cache (B,S,Hkv,D), into which the chunk's own K/V have already been
     written.  Key j is visible iff j <= q_offset + i, so the result equals
     one-shot causal prefill restricted to these T query rows.
 
-    Dense over the padded cache, like ``decode_attention`` — the traced
+    Over the whole padded cache, like ``decode_attention`` — the traced
     offset cannot go through ``chunked_attention`` (``q_offset`` is a
     nondiff_argnum of the flash vjp, so it would recompile per offset).
-    Fine at serve-engine cache sizes; a blockwise variant along the lines
-    of ``_fwd_blocks`` is the upgrade path if max_len grows."""
+    A cache longer than ``block_k`` (and a multiple of it) is swept one
+    block of keys at a time with an online softmax (``_fwd_blocks``), so
+    the logits held at once are T x block_k, not T x S: a 2048-token chunk
+    over a 17k-row cache would otherwise hold 4.6 GB of f32 logits."""
     b, t, hq, d = q.shape
     s = k_cache.shape[1]
     hkv = k_cache.shape[2]
     qg = _group_q(q, hkv).astype(jnp.float32) / math.sqrt(d)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache.astype(jnp.float32))
     iq = q_offset + jnp.arange(t)
+    if s > block_k and s % block_k == 0:
+        n = s // block_k
+        acc, _, l_f = _fwd_blocks(
+            qg, k_cache.reshape(b, n, block_k, hkv, d),
+            v_cache.reshape(b, n, block_k, hkv, d), iq, True, block_k)
+        out = acc / jnp.maximum(l_f[..., None], 1e-30)  # (b,hkv,g,t,d)
+        return jnp.moveaxis(out, 3, 1).reshape(b, t, hq, d).astype(q.dtype)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache.astype(jnp.float32))
     mask = jnp.arange(s)[None, :] <= iq[:, None]
     logits = jnp.where(mask[None, None, None], logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
@@ -261,6 +271,8 @@ class AttentionConfig:
     causal: bool = True
     block_k: int = 512
     use_chunked: bool = True
+    rope: bool = True              # False: no position embedding (NoPE)
+    softmax_scale: float = 0.0     # 0 => 1/sqrt(head_dim)
 
     @property
     def hd(self) -> int:
@@ -301,8 +313,13 @@ def _project_qkv(params, x, cfg: AttentionConfig, policy: DTypePolicy):
 
 
 def _apply_positions(q, k, positions, cfg: AttentionConfig):
+    """Rotary positions (unless the config has none) and a configured
+    softmax scale, folded into q: the attention kernels divide the logits
+    by sqrt(head_dim), so q carries ``softmax_scale * sqrt(head_dim)``."""
     from repro.models.layers import apply_rope, apply_mrope
-    if positions is None:
+    if cfg.softmax_scale:
+        q = q * (cfg.softmax_scale * math.sqrt(cfg.hd))
+    if positions is None or not cfg.rope:
         return q, k
     if cfg.mrope_sections is not None:
         if positions.ndim == 2:      # text-only: replicate plane ids

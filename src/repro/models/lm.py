@@ -19,6 +19,7 @@ Block kinds:
   attn       pre-norm GQA attention + dense FFN
   attn_moe   pre-norm GQA attention + MoE FFN (aux loss accumulated)
   mamba      pre-norm Mamba2 mixer (no FFN, Zamba2 style)
+  mamba_ffn  pre-norm Mamba2 mixer + dense FFN (Granite-4.0-H style)
   mlstm      pre-norm mLSTM mixer
   slstm      pre-norm sLSTM mixer
   gspn       pre-norm GSPN-2 sequence mixer (paper technique) + dense FFN
@@ -31,6 +32,7 @@ functions in ``KINDS``.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import jax
@@ -105,6 +107,16 @@ class LMConfig:
     # Scan-carry / accumulator dtype (DESIGN.md §10): stays f32 under the
     # default mixed-precision policy even when params/compute are bf16.
     carry_dtype: Any = jnp.float32
+    # Granite-style scalars: embeddings x embed_scale, every block branch
+    # x residual_scale before it is added, logits / logit_divisor; the
+    # attention softmax scale (0: 1/sqrt(head_dim)), rotary positions or
+    # none (NoPE), and the norms' epsilon (None: each norm's default).
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
+    attn_scale: float = 0.0
+    rope: bool = True
+    norm_eps: Optional[float] = None
 
     @property
     def hd(self) -> int:
@@ -161,7 +173,8 @@ def _attn_cfg(cfg: LMConfig, causal=True, cross=False):
         head_dim=cfg.head_dim, qkv_bias=cfg.qkv_bias,
         rope_theta=cfg.rope_theta,
         mrope_sections=None if cross else cfg.mrope_sections,
-        causal=causal, block_k=cfg.attn_block_k)
+        causal=causal, block_k=cfg.attn_block_k, rope=cfg.rope,
+        softmax_scale=cfg.attn_scale)
 
 
 def _moe_cfg(cfg: LMConfig):
@@ -175,7 +188,8 @@ def _moe_cfg(cfg: LMConfig):
 def _mamba_cfg(cfg: LMConfig):
     return ssm_mod.Mamba2Config(
         dim=cfg.d_model, d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
-        chunk=cfg.gla_chunk)
+        chunk=cfg.gla_chunk,
+        norm_eps=1e-6 if cfg.norm_eps is None else cfg.norm_eps)
 
 
 def _mlstm_cfg(cfg: LMConfig):
@@ -203,7 +217,27 @@ def _norm_init(cfg: LMConfig):
 
 
 def _norm_apply(cfg: LMConfig, p, x):
-    return (apply_rmsnorm if cfg.norm == "rmsnorm" else apply_layernorm)(p, x)
+    fn = apply_rmsnorm if cfg.norm == "rmsnorm" else apply_layernorm
+    return fn(p, x) if cfg.norm_eps is None else fn(p, x, cfg.norm_eps)
+
+
+def _res(cfg: LMConfig, x, y):
+    """Add a block branch to the residual stream, scaled as configured."""
+    return x + y if cfg.residual_scale == 1.0 else x + cfg.residual_scale * y
+
+
+def _embed(params, cfg: LMConfig, tokens):
+    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
+
+
+def _logits(params, cfg: LMConfig, x):
+    """Final norm and the vocabulary head (the tied embedding or ``head``)."""
+    x = _norm_apply(cfg, params["ln_f"], x)
+    head = (params["embed"].T if cfg.tie_embeddings
+            else params["head"]).astype(cfg.compute_dtype)
+    logits = x.astype(cfg.compute_dtype) @ head
+    return logits if cfg.logit_divisor == 1.0 else logits / cfg.logit_divisor
 
 
 def _ffn_init(key, cfg: LMConfig):
@@ -240,8 +274,8 @@ def _init_attn_block(key, cfg: LMConfig, with_ffn=True, cross=False):
 def _apply_attn_block(p, x, cfg, ctx, positions, enc_kv=None, moe=False):
     aux = jnp.zeros((), jnp.float32)
     h = _norm_apply(cfg, p["ln1"], x)
-    x = x + attn_mod.apply_attention(p["attn"], h, _attn_cfg(cfg),
-                                     positions=positions, policy=cfg.policy)
+    x = _res(cfg, x, attn_mod.apply_attention(
+        p["attn"], h, _attn_cfg(cfg), positions=positions, policy=cfg.policy))
     if enc_kv is not None:
         h = _norm_apply(cfg, p["ln_x"], x)
         x = x + attn_mod.apply_attention(
@@ -253,10 +287,10 @@ def _apply_attn_block(p, x, cfg, ctx, positions, enc_kv=None, moe=False):
                                    mesh=ctx.mesh, dp_axes=ctx.dp_axes,
                                    model_axis=ctx.model_axis,
                                    policy=cfg.policy)
-        x = x + y
+        x = _res(cfg, x, y)
     elif "ffn" in p:
         h = _norm_apply(cfg, p["ln2"], x)
-        x = x + _ffn_apply(cfg, p["ffn"], h)
+        x = _res(cfg, x, _ffn_apply(cfg, p["ffn"], h))
     return x, aux
 
 
@@ -264,7 +298,7 @@ def _apply_attn_block_decode(p, x, cfg, ctx, cache, enc_kv=None, moe=False):
     h = _norm_apply(cfg, p["ln1"], x)
     y, new_attn = attn_mod.apply_attention_decode(
         p["attn"], h, _attn_cfg(cfg), cache["attn"], policy=cfg.policy)
-    x = x + y
+    x = _res(cfg, x, y)
     if enc_kv is not None:
         h = _norm_apply(cfg, p["ln_x"], x)
         x = x + attn_mod.apply_attention(
@@ -275,10 +309,10 @@ def _apply_attn_block_decode(p, x, cfg, ctx, cache, enc_kv=None, moe=False):
         y, _ = moe_mod.apply_moe(p["moe"], h, _moe_cfg(cfg), mesh=ctx.mesh,
                                  dp_axes=ctx.dp_axes,
                                  model_axis=ctx.model_axis, policy=cfg.policy)
-        x = x + y
+        x = _res(cfg, x, y)
     elif "ffn" in p:
         h = _norm_apply(cfg, p["ln2"], x)
-        x = x + _ffn_apply(cfg, p["ffn"], h)
+        x = _res(cfg, x, _ffn_apply(cfg, p["ffn"], h))
     return x, {"attn": new_attn}
 
 
@@ -337,7 +371,8 @@ def _mk_attn_kind(moe=False, cross=False):
             out = attn_mod.full_attention(q, k, v, causal=True)
         out = out.reshape(b, s, acfg.n_heads * acfg.hd)
         pc = cfg.policy.cast(p["attn"])
-        x = x + (out.astype(cfg.policy.compute_dtype) @ pc["wo"]).astype(x.dtype)
+        x = _res(cfg, x, (out.astype(cfg.policy.compute_dtype)
+                          @ pc["wo"]).astype(x.dtype))
         pad = max_len - s
         cache = {"attn": {
             "k": jnp.pad(k.astype(cfg.compute_dtype),
@@ -357,17 +392,20 @@ def _mk_attn_kind(moe=False, cross=False):
                                      mesh=ctx.mesh, dp_axes=ctx.dp_axes,
                                      model_axis=ctx.model_axis,
                                      policy=cfg.policy)
-            x = x + y
+            x = _res(cfg, x, y)
         elif "ffn" in p:
             h = _norm_apply(cfg, p["ln2"], x)
-            x = x + _ffn_apply(cfg, p["ffn"], h)
+            x = _res(cfg, x, _ffn_apply(cfg, p["ffn"], h))
         return x, cache
 
-    def apply_prefill_chunk(p, x, cfg, ctx, cache, off, enc_kv=None):
+    def apply_prefill_chunk(p, x, cfg, ctx, cache, off, enc_kv=None,
+                            n_valid=None):
         """Consume a (B, T) prompt chunk at offset ``off`` (traced scalar):
         write the chunk's K/V into the cache in place and attend over the
         cache with the offset causal mask — equal to one-shot prefill
-        restricted to these T rows (DESIGN.md §9)."""
+        restricted to these T rows (DESIGN.md §9).  With ``n_valid`` the
+        rows past it are padding: their K/V land past the cache length,
+        where no query looks and decode overwrites them."""
         b, t, _ = x.shape
         acfg = _attn_cfg(cfg)
         h = _norm_apply(cfg, p["ln1"], x)
@@ -384,11 +422,12 @@ def _mk_attn_kind(moe=False, cross=False):
         out = attn_mod.chunk_prefill_attention(q, kc, vc, off)
         out = out.reshape(b, t, acfg.n_heads * acfg.hd)
         pc = cfg.policy.cast(p["attn"])
-        x = x + (out.astype(cfg.policy.compute_dtype)
-                 @ pc["wo"]).astype(x.dtype)
+        x = _res(cfg, x, (out.astype(cfg.policy.compute_dtype)
+                          @ pc["wo"]).astype(x.dtype))
         new_cache = {"attn": {
             "k": kc, "v": vc,
-            "length": jnp.full((b,), 0, jnp.int32) + off + t,
+            "length": jnp.full((b,), 0, jnp.int32) + off + (
+                t if n_valid is None else n_valid),
         }}
         if moe:
             h = _norm_apply(cfg, p["ln2"], x)
@@ -396,10 +435,10 @@ def _mk_attn_kind(moe=False, cross=False):
                                      mesh=ctx.mesh, dp_axes=ctx.dp_axes,
                                      model_axis=ctx.model_axis,
                                      policy=cfg.policy)
-            x = x + y
+            x = _res(cfg, x, y)
         elif "ffn" in p:
             h = _norm_apply(cfg, p["ln2"], x)
-            x = x + _ffn_apply(cfg, p["ffn"], h)
+            x = _res(cfg, x, _ffn_apply(cfg, p["ffn"], h))
         return x, new_cache
 
     return Kind(init, apply, apply_decode, cache_init, apply_prefill,
@@ -407,122 +446,122 @@ def _mk_attn_kind(moe=False, cross=False):
 
 
 def _mk_mixer_kind(name):
+    """A pre-norm sequence mixer; ``gspn`` and ``mamba_ffn`` (Granite-4.0-H
+    style) follow it with a pre-norm FFN, ``mamba`` (Zamba2 style) and the
+    xLSTM mixers do not."""
+    mixer = "mamba" if name == "mamba_ffn" else name
+    has_ffn = name in ("gspn", "mamba_ffn")
+
+    def ffn(p, x, cfg):
+        if not has_ffn:
+            return x
+        return _res(cfg, x, _ffn_apply(cfg, p["ffn"],
+                                       _norm_apply(cfg, p["ln2"], x)))
+
     def init(key, cfg):
         k1, k2 = jax.random.split(key)
         p = {"ln1": _norm_init(cfg)}
-        if name == "mamba":
+        if mixer == "mamba":
             p["mix"] = ssm_mod.init_mamba2(k1, _mamba_cfg(cfg),
                                            cfg.param_dtype)
-        elif name == "mlstm":
+        elif mixer == "mlstm":
             p["mix"] = xlstm_mod.init_mlstm(k1, _mlstm_cfg(cfg),
                                             cfg.param_dtype)
-        elif name == "slstm":
+        elif mixer == "slstm":
             p["mix"] = xlstm_mod.init_slstm(k1, _slstm_cfg(cfg),
                                             cfg.param_dtype)
-        elif name == "gspn":
+        elif mixer == "gspn":
             p["mix"] = gspn_core.init_gspn_seq_mixer(k1, _gspn_cfg(cfg))
+        if has_ffn:
             p["ln2"] = _norm_init(cfg)
             p["ffn"] = _ffn_init(k2, cfg)
         return p
 
     def apply(p, x, cfg, ctx, positions, enc_kv=None):
         h = _norm_apply(cfg, p["ln1"], x)
-        if name == "mamba":
-            x = x + ssm_mod.apply_mamba2(p["mix"], h, _mamba_cfg(cfg),
-                                         cfg.policy)
-        elif name == "mlstm":
-            x = x + xlstm_mod.apply_mlstm(p["mix"], h, _mlstm_cfg(cfg),
-                                          cfg.policy)
-        elif name == "slstm":
-            x = x + xlstm_mod.apply_slstm(p["mix"], h, _slstm_cfg(cfg),
-                                          cfg.policy)
-        elif name == "gspn":
-            x = x + gspn_core.apply_gspn_seq_mixer(
+        if mixer == "mamba":
+            y = ssm_mod.apply_mamba2(p["mix"], h, _mamba_cfg(cfg), cfg.policy)
+        elif mixer == "mlstm":
+            y = xlstm_mod.apply_mlstm(p["mix"], h, _mlstm_cfg(cfg),
+                                      cfg.policy)
+        elif mixer == "slstm":
+            y = xlstm_mod.apply_slstm(p["mix"], h, _slstm_cfg(cfg),
+                                      cfg.policy)
+        else:
+            y = gspn_core.apply_gspn_seq_mixer(
                 p["mix"], h, _gspn_cfg(cfg),
                 mesh=ctx.mesh if ctx is not None else None)
-            h = _norm_apply(cfg, p["ln2"], x)
-            x = x + _ffn_apply(cfg, p["ffn"], h)
-        return x, jnp.zeros((), jnp.float32)
+        return ffn(p, _res(cfg, x, y), cfg), jnp.zeros((), jnp.float32)
 
     def apply_decode(p, x, cfg, ctx, cache, enc_kv=None):
         h = _norm_apply(cfg, p["ln1"], x)
-        if name == "mamba":
+        if mixer == "mamba":
             y, new = ssm_mod.apply_mamba2_decode(p["mix"], h,
                                                  _mamba_cfg(cfg), cache,
                                                  cfg.policy)
-            return x + y, new
-        if name == "mlstm":
+        elif mixer == "mlstm":
             y, new = xlstm_mod.apply_mlstm_decode(p["mix"], h,
                                                   _mlstm_cfg(cfg), cache,
                                                   cfg.policy)
-            return x + y, new
-        if name == "slstm":
+        elif mixer == "slstm":
             y, new = xlstm_mod.apply_slstm_decode(p["mix"], h,
                                                   _slstm_cfg(cfg), cache,
                                                   cfg.policy)
-            return x + y, new
-        if name == "gspn":
+        else:
             y, new = gspn_decode_step(p["mix"], h, _gspn_cfg(cfg), cache)
-            x = x + y
-            h = _norm_apply(cfg, p["ln2"], x)
-            x = x + _ffn_apply(cfg, p["ffn"], h)
-            return x, new
-        raise ValueError(name)
+        return ffn(p, _res(cfg, x, y), cfg), new
 
     def cache_init(batch, max_len, cfg):
-        if name == "mamba":
+        if mixer == "mamba":
             return ssm_mod.init_mamba2_cache(batch, _mamba_cfg(cfg),
                                              jnp.float32)
-        if name == "mlstm":
+        if mixer == "mlstm":
             return xlstm_mod.init_mlstm_cache(batch, _mlstm_cfg(cfg))
-        if name == "slstm":
+        if mixer == "slstm":
             return xlstm_mod.init_slstm_cache(batch, _slstm_cfg(cfg))
-        if name == "gspn":
-            return init_gspn_decode_cache(batch, _gspn_cfg(cfg))
-        raise ValueError(name)
+        return init_gspn_decode_cache(batch, _gspn_cfg(cfg))
 
     def apply_prefill(p, x, cfg, ctx, positions, max_len, enc_kv=None):
         h = _norm_apply(cfg, p["ln1"], x)
-        if name == "mamba":
+        if mixer == "mamba":
             y, cache = ssm_mod.apply_mamba2_prefill(p["mix"], h,
                                                     _mamba_cfg(cfg),
                                                     cfg.policy)
-            return x + y, cache
-        if name == "mlstm":
+        elif mixer == "mlstm":
             y, cache = xlstm_mod.apply_mlstm_prefill(p["mix"], h,
                                                      _mlstm_cfg(cfg),
                                                      cfg.policy)
-            return x + y, cache
-        if name == "slstm":
+        elif mixer == "slstm":
             y, cache = xlstm_mod.apply_slstm_prefill(p["mix"], h,
                                                      _slstm_cfg(cfg),
                                                      cfg.policy)
-            return x + y, cache
-        if name == "gspn":
+        else:
             y, cache = gspn_core.apply_gspn_seq_mixer(
                 p["mix"], h, _gspn_cfg(cfg), return_cache=True,
                 mesh=ctx.mesh if ctx is not None else None)
-            x = x + y
-            h = _norm_apply(cfg, p["ln2"], x)
-            x = x + _ffn_apply(cfg, p["ffn"], h)
-            return x, cache
-        raise ValueError(name)
+        return ffn(p, _res(cfg, x, y), cfg), cache
 
-    def apply_prefill_chunk(p, x, cfg, ctx, cache, off, enc_kv=None):
-        # Only the GSPN mixer has a resumable chunked scan; the other
-        # mixers' prefill paths start from a zero state, so the engine
-        # keeps them on one-shot prefill (supports_chunked_prefill).
+    def apply_prefill_chunk(p, x, cfg, ctx, cache, off, enc_kv=None,
+                            n_valid=None):
+        # The GSPN mixer resumes its chunked scan from the cached boundary
+        # rows, Mamba-2 its SSD scan from the cached state and conv tail;
+        # the xLSTM mixers' prefill starts from a zero state, so the
+        # engine keeps them on one-shot prefill (supports_chunked_prefill).
         h = _norm_apply(cfg, p["ln1"], x)
-        y, new = gspn_core.gspn_seq_prefill_chunk(
-            p["mix"], h, _gspn_cfg(cfg), cache,
-            mesh=ctx.mesh if ctx is not None else None)
-        x = x + y
-        h = _norm_apply(cfg, p["ln2"], x)
-        x = x + _ffn_apply(cfg, p["ffn"], h)
-        return x, new
+        if mixer == "mamba":
+            y, new = ssm_mod.apply_mamba2_decode(p["mix"], h,
+                                                 _mamba_cfg(cfg), cache,
+                                                 cfg.policy, n_valid=n_valid)
+        else:
+            if n_valid is not None:
+                raise ValueError("the GSPN scan has no mask for padding")
+            y, new = gspn_core.gspn_seq_prefill_chunk(
+                p["mix"], h, _gspn_cfg(cfg), cache,
+                mesh=ctx.mesh if ctx is not None else None)
+        return ffn(p, _res(cfg, x, y), cfg), new
 
     return Kind(init, apply, apply_decode, cache_init, apply_prefill,
-                apply_prefill_chunk if name == "gspn" else None)
+                apply_prefill_chunk if mixer in ("gspn", "mamba") else None)
 
 
 KINDS = {
@@ -530,6 +569,7 @@ KINDS = {
     "attn_moe": _mk_attn_kind(moe=True),
     "xattn": _mk_attn_kind(moe=False, cross=True),
     "mamba": _mk_mixer_kind("mamba"),
+    "mamba_ffn": _mk_mixer_kind("mamba_ffn"),
     "mlstm": _mk_mixer_kind("mlstm"),
     "slstm": _mk_mixer_kind("slstm"),
     "gspn": _mk_mixer_kind("gspn"),
@@ -723,7 +763,7 @@ def apply_lm(params, cfg: LMConfig, tokens, *, ctx: Ctx = None,
     """
     ctx = ctx or Ctx()
     pol = cfg.policy
-    x = params["embed"].astype(pol.compute_dtype)[tokens]
+    x = _embed(params, cfg, tokens)
     if vision_embeds is not None:
         sv = vision_embeds.shape[1]
         x = jnp.concatenate(
@@ -787,11 +827,7 @@ def apply_lm(params, cfg: LMConfig, tokens, *, ctx: Ctx = None,
         (x, aux_total), _ = jax.lax.scan(unit_body, (x, aux_total),
                                          unit_params)
 
-    x = _norm_apply(cfg, params["ln_f"], ctx.anchor(x))
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["head"]).astype(pol.compute_dtype)
-    logits = x.astype(pol.compute_dtype) @ head
-    return logits, aux_total
+    return _logits(params, cfg, ctx.anchor(x)), aux_total
 
 
 def lm_loss(params, cfg: LMConfig, batch, ctx: Ctx = None):
@@ -813,7 +849,7 @@ def lm_prefill(params, cfg: LMConfig, tokens, max_len: int, *,
     """Returns (logits (B,S,V), caches, enc_kv)."""
     ctx = ctx or Ctx()
     pol = cfg.policy
-    x = params["embed"].astype(pol.compute_dtype)[tokens]
+    x = _embed(params, cfg, tokens)
     if vision_embeds is not None:
         sv = vision_embeds.shape[1]
         x = jnp.concatenate(
@@ -870,11 +906,7 @@ def lm_prefill(params, cfg: LMConfig, tokens, max_len: int, *,
         x, unit_caches = jax.lax.scan(unit_body, x, unit_params)
         caches.update(unit_caches)
 
-    x = _norm_apply(cfg, params["ln_f"], ctx.anchor(x))
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["head"]).astype(pol.compute_dtype)
-    logits = x.astype(pol.compute_dtype) @ head
-    return logits, caches, enc_kv
+    return _logits(params, cfg, ctx.anchor(x)), caches, enc_kv
 
 
 # ---------------------------------------------------------------------------
@@ -883,10 +915,35 @@ def lm_prefill(params, cfg: LMConfig, tokens, max_len: int, *,
 # lm_decode_step — it is the same stage walk with apply_prefill_chunk.
 # ---------------------------------------------------------------------------
 
+def _scan_stage(body, x, stacked):
+    """Walk a prelude stage's layers, ``body(x, (layer_params, cache))``
+    giving ``(x, new_cache)``, and return ``(x, new stacked caches)``.
+
+    The stacked caches are the loop's carry, each layer's update written
+    back in place (a ``lax.scan`` would build the new caches in a fresh
+    stacked buffer and copy it out, holding two copies of every stage's
+    state: 4.3 GB more than the pool at granite-4.0-h-micro's 32 slots).
+    A stage of one layer is applied directly."""
+    params, caches = stacked
+    n = jax.tree.leaves(caches)[0].shape[0]
+    if n == 1:
+        x, new = body(x, jax.tree.map(lambda a: a[0], stacked))
+        return x, jax.tree.map(lambda a, c: c[None].astype(a.dtype),
+                               caches, new)
+
+    def step(i, carry):
+        h, cs = carry
+        h, new = body(h, jax.tree.map(lambda a: a[i], (params, cs)))
+        return h, jax.tree.map(lambda a, c: a.at[i].set(c.astype(a.dtype)),
+                               cs, new)
+    return jax.lax.fori_loop(0, n, step, (x, caches))
+
+
 def supports_chunked_prefill(cfg: LMConfig) -> bool:
     """True iff every stage kind of ``cfg`` implements the incremental
-    prefill contract (attention families and the GSPN mixer).  SSM/xLSTM
-    mixers and encoder-decoder models fall back to one-shot prefill."""
+    prefill contract (attention families, the GSPN mixer and Mamba-2).
+    xLSTM mixers and encoder-decoder models fall back to one-shot
+    prefill."""
     if cfg.encoder_layers:
         return False
     kinds = {kind for _, kind, _ in cfg.stages()}
@@ -899,17 +956,33 @@ def supports_chunked_prefill(cfg: LMConfig) -> bool:
     return True
 
 
+def supports_padded_chunks(cfg: LMConfig) -> bool:
+    """True iff a prompt chunk may end in padding (``lm_prefill_chunk``'s
+    ``n_valid``): attention keeps it past the cache length and Mamba-2
+    gives it no step.  The GSPN scan has no such mask, and padding would
+    take expert capacity from the tokens of a mixture of experts."""
+    kinds = {kind for _, kind, _ in cfg.stages()}
+    return (supports_chunked_prefill(cfg)
+            and kinds <= {"attn", "mamba", "mamba_ffn"})
+
+
 def prefill_chunk_alignment(cfg: LMConfig) -> int:
-    """Chunk boundaries must start at GSPN grid-row boundaries, so chunk
-    sizes are rounded to a multiple of the fold width when a gspn stage is
-    present (gspn_seq_prefill_chunk contract); 1 otherwise."""
-    if any(kind == "gspn" for _, kind, _ in cfg.stages()):
-        return max(1, cfg.gspn_row_width)
-    return 1
+    """Chunk boundaries must start at GSPN grid-row boundaries
+    (gspn_seq_prefill_chunk contract), and start at Mamba-2 scan-chunk
+    boundaries so every prompt chunk runs whole SSD chunks; chunk sizes
+    are rounded to a multiple of both (1 without either kind)."""
+    kinds = {kind for _, kind, _ in cfg.stages()}
+    align = 1
+    if "gspn" in kinds:
+        align = max(1, cfg.gspn_row_width)
+    if kinds & {"mamba", "mamba_ffn"}:
+        align = math.lcm(align, cfg.gla_chunk)
+    return align
 
 
 def lm_prefill_chunk(params, cfg: LMConfig, tokens, caches, off, *,
-                     ctx: Ctx = None, with_logits: bool = True):
+                     ctx: Ctx = None, with_logits: bool = True,
+                     n_valid=None):
     """Consume prompt tokens (B, T) starting at absolute offset ``off``
     (scalar int32, traced — one compile per chunk LENGTH, not per offset)
     against ``caches`` shaped like :func:`init_lm_cache` output.  Returns
@@ -920,11 +993,15 @@ def lm_prefill_chunk(params, cfg: LMConfig, tokens, caches, off, *,
     ``with_logits=False`` (static) returns (None, new_caches), skipping
     the final norm + vocab-head matmul — only the LAST chunk's logits
     feed sampling, so intermediate chunks in the serve hot path need not
-    pay an O(T·V) head projection each."""
+    pay an O(T·V) head projection each.
+
+    ``n_valid`` (traced scalar, models with :func:`supports_padded_chunks`):
+    only the first ``n_valid`` tokens are the prompt's, the rest padding
+    that leaves the caches as if the chunk had ended at ``n_valid``; the
+    logits are then those of the last prompt token alone, (B, 1, V)."""
     ctx = ctx or Ctx()
-    pol = cfg.policy
     off = jnp.asarray(off, jnp.int32)
-    x = ctx.anchor(params["embed"].astype(pol.compute_dtype)[tokens])
+    x = ctx.anchor(_embed(params, cfg, tokens))
     new_caches = {}
     stages = cfg.stages()
 
@@ -936,12 +1013,11 @@ def lm_prefill_chunk(params, cfg: LMConfig, tokens, caches, off, *,
         def body(h, inp, kf=kf):
             lp, cache = inp
             h, new = kf.apply_prefill_chunk(lp, ctx.anchor(h), cfg, ctx,
-                                            cache, off)
+                                            cache, off, n_valid=n_valid)
             return ctx.anchor(h), new
 
-        x, new = jax.lax.scan(body, x,
-                              (params["stages"][f"s{si}_{kind}"],
-                               caches[f"s{si}_{kind}"]))
+        x, new = _scan_stage(body, x, (params["stages"][f"s{si}_{kind}"],
+                                       caches[f"s{si}_{kind}"]))
         new_caches[f"s{si}_{kind}"] = new
 
     unit_stages = [(si, kind) for si, (w, kind, n) in enumerate(stages)
@@ -956,7 +1032,8 @@ def lm_prefill_chunk(params, cfg: LMConfig, tokens, caches, off, *,
                 def body(hh, pc, kf=kf):
                     lp, cache = pc
                     hh, new = kf.apply_prefill_chunk(lp, ctx.anchor(hh), cfg,
-                                                     ctx, cache, off)
+                                                     ctx, cache, off,
+                                                     n_valid=n_valid)
                     return ctx.anchor(hh), new
 
                 h, new = jax.lax.scan(
@@ -966,7 +1043,7 @@ def lm_prefill_chunk(params, cfg: LMConfig, tokens, caches, off, *,
             if cfg.shared_attn:
                 h, new_sh = KINDS["attn"].apply_prefill_chunk(
                     params["shared_attn"], h, cfg, ctx,
-                    unit_caches["shared_attn"], off)
+                    unit_caches["shared_attn"], off, n_valid=n_valid)
                 new_unit["shared_attn"] = new_sh
             return h, new_unit
 
@@ -981,11 +1058,9 @@ def lm_prefill_chunk(params, cfg: LMConfig, tokens, caches, off, *,
 
     if not with_logits:
         return None, new_caches
-    x = _norm_apply(cfg, params["ln_f"], ctx.anchor(x))
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["head"]).astype(pol.compute_dtype)
-    logits = x.astype(pol.compute_dtype) @ head
-    return logits, new_caches
+    if n_valid is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+    return _logits(params, cfg, ctx.anchor(x)), new_caches
 
 
 # ---------------------------------------------------------------------------
@@ -1018,8 +1093,7 @@ def lm_decode_step(params, cfg: LMConfig, token, caches, *, ctx: Ctx = None,
                    enc_kv=None):
     """token: (B, 1) int32.  Returns (logits (B,1,V), new_caches)."""
     ctx = ctx or Ctx()
-    pol = cfg.policy
-    x = ctx.anchor(params["embed"].astype(pol.compute_dtype)[token])
+    x = ctx.anchor(_embed(params, cfg, token))
     new_caches = {}
     stages = cfg.stages()
 
@@ -1033,9 +1107,8 @@ def lm_decode_step(params, cfg: LMConfig, token, caches, *, ctx: Ctx = None,
             h, new = kf.apply_decode(lp, h, cfg, ctx, cache, enc_kv=enc_kv)
             return h, new
 
-        x, new = jax.lax.scan(body, x,
-                              (params["stages"][f"s{si}_{kind}"],
-                               caches[f"s{si}_{kind}"]))
+        x, new = _scan_stage(body, x, (params["stages"][f"s{si}_{kind}"],
+                                       caches[f"s{si}_{kind}"]))
         new_caches[f"s{si}_{kind}"] = new
 
     unit_stages = [(si, kind) for si, (w, kind, n) in enumerate(stages)
@@ -1073,11 +1146,7 @@ def lm_decode_step(params, cfg: LMConfig, token, caches, *, ctx: Ctx = None,
         x, new_unit = jax.lax.scan(unit_body, x, (unit_params, unit_caches))
         new_caches.update(new_unit)
 
-    x = _norm_apply(cfg, params["ln_f"], x)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["head"]).astype(pol.compute_dtype)
-    logits = x.astype(pol.compute_dtype) @ head
-    return logits, new_caches
+    return _logits(params, cfg, x), new_caches
 
 
 # ---------------------------------------------------------------------------
@@ -1106,10 +1175,11 @@ def count_active_params(cfg: LMConfig) -> int:
             total += reps * (attn_p + moe_mod.moe_active_param_count(mcfg))
         elif kind == "xattn":
             total += reps * (2 * attn_p + ffn_p)
-        elif kind == "mamba":
+        elif kind in ("mamba", "mamba_ffn"):
             mc = _mamba_cfg(cfg)
             total += reps * (d * (2 * mc.d_inner + 2 * mc.d_state
-                                  + mc.n_heads) + mc.d_inner * d)
+                                  + mc.n_heads) + mc.d_inner * d
+                             + (ffn_p if kind == "mamba_ffn" else 0))
         elif kind == "mlstm":
             mc = _mlstm_cfg(cfg)
             total += reps * (d * (4 * mc.d_inner + 2 * mc.n_heads)

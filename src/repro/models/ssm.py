@@ -26,11 +26,13 @@ from repro.models.layers import (DTypePolicy, DEFAULT_POLICY, dense_init,
                                  init_causal_conv1d, apply_causal_conv1d)
 
 
-def chunked_gla(q, k, v, log_decay, chunk: int = 256):
+def chunked_gla(q, k, v, log_decay, chunk: int = 256, initial_state=None):
     """Gated linear attention, chunk-parallel.
 
-    q, k: (B, S, H, Dk); v: (B, S, H, Dv); log_decay: (B, S, H) (≤ 0).
-    Returns y: (B, S, H, Dv) f32 and final state (B, H, Dk, Dv).
+    q, k: (B, S, H, Dk); v: (B, S, H, Dv); log_decay: (B, S, H) (≤ 0);
+    ``initial_state`` (B, H, Dk, Dv), the state before the first token
+    (zero when None) — a prompt chunk resumes from the state the previous
+    chunk left.  Returns y: (B, S, H, Dv) f32 and final state (B, H, Dk, Dv).
     """
     b, s, h, dk = q.shape
     dv = v.shape[-1]
@@ -63,7 +65,8 @@ def chunked_gla(q, k, v, log_decay, chunk: int = 256):
         new = state * dec_c[..., None, None] + kv_c
         return new, state                                   # emit state BEFORE chunk
 
-    s0 = jnp.zeros((b, h, dk, dv), jnp.float32)
+    s0 = (jnp.zeros((b, h, dk, dv), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
     final_state, states_before = jax.lax.scan(
         scan_body, s0,
         (jnp.moveaxis(chunk_kv, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
@@ -98,6 +101,7 @@ class Mamba2Config:
     expand: int = 2
     conv_k: int = 4
     chunk: int = 256
+    norm_eps: float = 1e-6
 
     @property
     def d_inner(self) -> int:
@@ -125,24 +129,37 @@ def init_mamba2(key, cfg: Mamba2Config, dtype=jnp.float32):
 
 
 def _mamba2_inner(params, x, cfg: Mamba2Config, policy, conv_state=None,
-                  ssm_state=None):
-    """Shared forward. If states given -> streaming (decode) mode."""
+                  ssm_state=None, n_valid=None):
+    """Shared forward over x (B, S, D).  Without states it starts from zero
+    state (prefill); with them it resumes: one token runs the decode
+    update, a longer chunk the chunked scan from ``ssm_state``.  The conv
+    tail (the last ``conv_k - 1`` raw xBC rows) carries across calls, also
+    for chunks shorter than the tail.
+
+    ``n_valid`` (traced scalar): only the first ``n_valid`` rows of a
+    resumed chunk are tokens, the rest padding.  Padded rows get dt = 0,
+    so the state passes through them unchanged, and the conv tail ends at
+    the last token; their outputs are not meaningful."""
     b, s, _ = x.shape
     di, ns, nh, hd = cfg.d_inner, cfg.d_state, cfg.n_heads, cfg.head_dim
     p = policy.cast(params)
     proj = (x.astype(policy.compute_dtype) @ p["in_proj"]).astype(jnp.float32)
     z, xbc, dt_raw = jnp.split(proj, [di, 2 * di + 2 * ns], axis=-1)
-    xbc_raw = xbc
+    if conv_state is None:
+        conv_state = jnp.zeros((b, cfg.conv_k - 1, xbc.shape[-1]),
+                               jnp.float32)
+    raw = xbc
     xbc, new_conv = apply_causal_conv1d(params["conv"], xbc, conv_state)
-    if conv_state is None and cfg.conv_k > 1:
-        # prefill: conv tail = last k-1 raw inputs (zero-padded on the left)
-        pad = max(cfg.conv_k - 1 - s, 0)
-        tail = jnp.pad(xbc_raw, ((0, 0), (pad, 0), (0, 0)))
-        new_conv = tail[:, -(cfg.conv_k - 1):]
+    if n_valid is not None:
+        new_conv = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([conv_state.astype(jnp.float32), raw], axis=1),
+            n_valid, cfg.conv_k - 1, axis=1)
     xbc = jax.nn.silu(xbc.astype(jnp.float32))
     x_ssm, bmat, cmat = jnp.split(xbc, [di, di + ns], axis=-1)
 
     dt = jax.nn.softplus(dt_raw + params["dt_bias"])        # (B,S,H)
+    if n_valid is not None:
+        dt = jnp.where(jnp.arange(s)[None, :, None] < n_valid, dt, 0.0)
     log_a = -jnp.exp(params["a_log"])                        # (H,) < 0
     log_decay = log_a * dt                                   # (B,S,H)
 
@@ -151,17 +168,21 @@ def _mamba2_inner(params, x, cfg: Mamba2Config, policy, conv_state=None,
     k = jnp.broadcast_to(bmat[:, :, None, :], (b, s, nh, ns))
     q = jnp.broadcast_to(cmat[:, :, None, :], (b, s, nh, ns))
 
-    if ssm_state is None:
-        y, final_state = chunked_gla(k=k, q=q, v=v, log_decay=log_decay,
-                                     chunk=cfg.chunk)
-    else:
+    if ssm_state is not None and s == 1:
         y, final_state = gla_decode_step(
             ssm_state, q[:, 0], k[:, 0], v[:, 0], log_decay[:, 0])
         y = y[:, None]
+    else:
+        with jax.named_scope("mamba2.ssd"):
+            y, final_state = chunked_gla(k=k, q=q, v=v, log_decay=log_decay,
+                                         chunk=cfg.chunk,
+                                         initial_state=ssm_state)
 
     y = y + params["d_skip"][None, None, :, None] * xh
     y = y.reshape(b, s, di)
-    y = apply_rmsnorm(params["norm"], y) * jax.nn.silu(z)
+    # Gated RMSNorm as published (Mamba-2's RMSNormGated with
+    # norm_before_gate=False): the gate multiplies before the norm.
+    y = apply_rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = (y.astype(policy.compute_dtype) @ p["out_proj"]).astype(x.dtype)
     return out, new_conv, final_state
 
@@ -181,11 +202,13 @@ def apply_mamba2_prefill(params, x, cfg: Mamba2Config,
 
 
 def apply_mamba2_decode(params, x, cfg: Mamba2Config, cache,
-                        policy: DTypePolicy = DEFAULT_POLICY):
-    """x (B,1,D); cache {'conv': (B,k-1,conv_dim), 'ssm': (B,H,Dk,Dv)}."""
+                        policy: DTypePolicy = DEFAULT_POLICY, n_valid=None):
+    """x (B,T,D) resumed from cache {'conv': (B,k-1,conv_dim), 'ssm':
+    (B,H,Dk,Dv)}: one decode token (T = 1) or a prompt chunk, of which
+    the first ``n_valid`` rows are tokens (all when None)."""
     out, new_conv, new_ssm = _mamba2_inner(
         params, x, cfg, policy, conv_state=cache["conv"],
-        ssm_state=cache["ssm"])
+        ssm_state=cache["ssm"], n_valid=n_valid)
     return out, {"conv": new_conv.astype(cache["conv"].dtype),
                  "ssm": new_ssm}
 

@@ -87,24 +87,35 @@ def _init_block(key, cfg: GSPNVisionConfig, dim: int):
     }
 
 
-def _anchor(x, ctx):
-    """Activation constraint: batch over dp AND channels over the model
-    axis.  A dp-only anchor killed the 10.7 GB/step of reshard all-gathers
-    but forfeited channel TP (measured 12× redundant compute on
-    img_train_224); anchoring both dims keeps the partitioner in the
-    batch×channel hybrid layout that matches the FFN weight sharding."""
-    if ctx is None or ctx.mesh is None:
-        return x
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def activation_spec(cfg: GSPNVisionConfig, ctx, shape):
+    """Layout of a (B, H, W, C) activation on ``ctx.mesh``: batch over dp
+    AND channels over the model axis.  A dp-only anchor killed the 10.7
+    GB/step of reshard all-gathers but forfeited channel TP (measured 12×
+    redundant compute on img_train_224); anchoring both dims keeps the
+    partitioner in the batch×channel hybrid layout that matches the FFN
+    weight sharding.  With ``impl="sp"`` the image rows are also split
+    over the seq axis, so every layer's activations, not only the scans,
+    are divided among the devices (the path that lets a grid exceed one
+    device's memory)."""
+    from jax.sharding import PartitionSpec as P
     from repro.parallel.sharding import sanitize_spec
     dp = ctx.dp_axes if len(ctx.dp_axes) > 1 else ctx.dp_axes[0]
-    spec = (dp,) + (None,) * (x.ndim - 2) + (ctx.model_axis,)
-    spec = sanitize_spec(P(*spec), x.shape, ctx.mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(ctx.mesh, spec))
+    rows = cfg.seq_axis if cfg.impl == "sp" else None
+    spec = (dp, rows) + (None,) * (len(shape) - 3) + (ctx.model_axis,)
+    return sanitize_spec(P(*spec), shape, ctx.mesh)
+
+
+def _anchor(x, ctx, cfg: GSPNVisionConfig):
+    """Constrain an activation to :func:`activation_spec`."""
+    if ctx is None or ctx.mesh is None:
+        return x
+    from jax.sharding import NamedSharding
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(ctx.mesh, activation_spec(cfg, ctx, x.shape)))
 
 
 def _apply_block(p, x, cfg: GSPNVisionConfig, dim: int, ctx=None):
-    x = _anchor(x, ctx)
+    x = _anchor(x, ctx, cfg)
     x = x + apply_dwconv2d(p["lpu"], x)                       # LPU
     h = apply_layernorm(p["ln1"], x)
     # impl="sp" shards every directional scan over the mesh's seq axis
@@ -113,12 +124,12 @@ def _apply_block(p, x, cfg: GSPNVisionConfig, dim: int, ctx=None):
     x = x + gspn_core.apply_gspn_attention(
         p["gspn"], h, _gspn_attn_cfg(cfg, dim),
         mesh=ctx.mesh if ctx is not None else None)
-    x = _anchor(x, ctx)
+    x = _anchor(x, ctx, cfg)
     x = x + apply_dwconv2d(p["lpu2"], x)                      # LPU before FFN
     h = apply_layernorm(p["ln2"], x)
     b, hh, ww, c = h.shape
     y = apply_gelu_mlp(p["mlp"], h.reshape(b, hh * ww, c), cfg.policy)
-    return _anchor(x + y.reshape(b, hh, ww, c), ctx)
+    return _anchor(x + y.reshape(b, hh, ww, c), ctx, cfg)
 
 
 def init_vision(key, cfg: GSPNVisionConfig):
@@ -145,7 +156,7 @@ def init_vision(key, cfg: GSPNVisionConfig):
 
 def apply_vision(params, x, cfg: GSPNVisionConfig, ctx=None):
     """x: (B, H, W, 3) -> logits (B, n_classes)."""
-    x = _anchor(_conv_apply(params["stem"], x, 4), ctx)
+    x = _anchor(_conv_apply(params["stem"], x, 4), ctx, cfg)
     for si, (dim, depth) in enumerate(zip(cfg.dims, cfg.depths)):
         stage = params["stages"][si]
 
@@ -154,7 +165,7 @@ def apply_vision(params, x, cfg: GSPNVisionConfig, ctx=None):
 
         x, _ = jax.lax.scan(body, x, stage["blocks"])
         if "down" in stage:
-            x = _anchor(_conv_apply(stage["down"], x, 2), ctx)
+            x = _anchor(_conv_apply(stage["down"], x, 2), ctx, cfg)
     x = apply_layernorm(params["ln_f"], x)
     x = jnp.mean(x, axis=(1, 2))
     return (x.astype(jnp.float32)

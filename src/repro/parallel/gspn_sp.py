@@ -430,7 +430,7 @@ def _block_scan(cfg: SPConfig, x, wl, wc, wr, lam, *, reverse: bool):
     b_last = h_loc[:, 0, :] if reverse else h_loc[:, -1, :]
     with jax.named_scope("sp.transfer_operator"):
         t = block_transfer_operator(wl, wc, wr, reverse=reverse)
-    with jax.named_scope("sp.exchange"):
+    with jax.named_scope("gspn_sp.exchange"):
         b_in = _exchange(t, b_last, cfg, reverse=reverse)
     with jax.named_scope("sp.correction"):
         h = h_loc + propagate_boundary(b_in, wl, wc, wr, reverse=reverse)
@@ -533,7 +533,7 @@ def _issue_pair_exchange(cfg: SPConfig, t2, b2, edge2):
         parts.append(edge2.reshape(2, 3 * gw, w))
     payload = jnp.concatenate(parts, axis=1).astype(
         jnp.dtype(cfg.boundary_dtype))
-    with jax.named_scope("sp.exchange"):
+    with jax.named_scope("gspn_sp.exchange"):
         return jax.lax.all_gather(payload, cfg.axis_name)
 
 

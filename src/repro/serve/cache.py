@@ -26,6 +26,7 @@ is built on, and is layout-aware — prelude/shared stages stack caches as
 from __future__ import annotations
 
 import collections
+import functools
 import hashlib
 import threading
 
@@ -37,16 +38,29 @@ from repro import obs
 from repro.models import lm as lm_mod
 
 
+#: Cache leaves that hold attention history (one row per token, written
+#: once); every other floating leaf is recurrent state (GSPN rows, Mamba-2
+#: SSM state and conv tail), rewritten at every step.
+KV_LEAVES = ("k", "v")
+
+
 def narrow_state(tree, state_dtype):
-    """Cast every floating leaf of a cache pytree to ``state_dtype``
-    (DESIGN.md §10); integer leaves (lengths, positions) pass through.
-    The single definition of the at-rest narrowing rule — the pool's
-    init/update and the engine's jitted decode all route through it."""
+    """Cast the attention KV leaves of a cache pytree to ``state_dtype``
+    (DESIGN.md §10).  Recurrent state keeps the dtype it is computed in,
+    the f32 carry dtype: it integrates every earlier token, so rounding it
+    at rest would re-inject error at every step, where a KV row is rounded
+    once.  Integer leaves (lengths, positions) pass through.  The single
+    definition of the at-rest narrowing rule — the pool's init/update and
+    the engine's jitted decode all route through it."""
     if state_dtype is None:
         return tree
-    return jax.tree.map(
-        lambda a: a.astype(state_dtype)
-        if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+
+    def narrow(path, a):
+        name = getattr(path[-1], "key", None)
+        if name in KV_LEAVES and jnp.issubdtype(a.dtype, jnp.floating):
+            return a.astype(state_dtype)
+        return a
+    return jax.tree_util.tree_map_with_path(narrow, tree)
 
 
 def update_cache_slots(cfg, caches, new_caches, slots):
@@ -73,6 +87,20 @@ def update_cache_slots(cfg, caches, new_caches, slots):
             axis = 2
         out[key] = jax.tree.map(upd(axis), sub, new_caches[key])
     return out
+
+
+# The pool's zeroed caches, made by one program straight into their final
+# buffers (eagerly, each stage's stacking would copy it once more).
+empty_caches = jax.jit(lm_mod.init_lm_cache, static_argnums=(0, 1, 2))
+
+
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+def commit_slots(cfg, caches, new_caches, slots):
+    """``update_cache_slots`` as one compiled scatter that writes the
+    donated pool in place (an eager scatter would hold the old and the new
+    pool at once); compiled once per configuration and shape, whichever
+    pool calls it."""
+    return update_cache_slots(cfg, caches, new_caches, slots)
 
 
 def _prefix_metrics():
@@ -183,13 +211,16 @@ class StateCachePool:
     exhaustion (the scheduler's backpressure signal — requests then wait
     in the admission queue).
 
-    ``state_dtype`` (DESIGN.md §10) narrows every floating cache leaf —
-    attention KV pages and GSPN/SSM propagation state alike — to the
-    given dtype at rest (integer leaves such as lengths/positions are
-    untouched).  bf16 halves the pool's bytes, which doubles the decode
-    batch that fits a fixed memory budget; ``commit``/``update`` casts on
-    scatter, and every consumer already lifts state back to f32 compute
-    at use, so narrowing is a storage decision, not a compute one.
+    ``state_dtype`` (DESIGN.md §10) narrows the attention KV pages to the
+    given dtype at rest; recurrent state (GSPN rows, Mamba-2 SSM state and
+    conv tail) stays at the f32 carry dtype, and integer leaves such as
+    lengths/positions are untouched (``narrow_state``).  bf16 KV halves the
+    bytes of a KV pool, which doubles the decode batch that fits a fixed
+    memory budget; ``commit``/``update`` casts on scatter, and every
+    consumer already lifts state back to f32 compute at use, so narrowing
+    is a storage decision, not a compute one.  Two kinds of state live
+    side by side in one pool: O(1) recurrent state and O(``max_len``) KV,
+    both indexed by the slot's batch row.
     """
 
     def __init__(self, cfg, n_slots: int, max_len: int, state_dtype=None):
@@ -198,10 +229,27 @@ class StateCachePool:
         self.max_len = max_len
         self.state_dtype = (None if state_dtype is None
                             else jnp.dtype(state_dtype))
-        self.caches = narrow_state(
-            lm_mod.init_lm_cache(cfg, n_slots, max_len), self.state_dtype)
+        self.caches = narrow_state(empty_caches(cfg, n_slots, max_len),
+                                   self.state_dtype)
         self._free = list(range(n_slots - 1, -1, -1))   # pop() yields slot 0
         self._used = set()
+        for kind, n in self.bytes_by_kind().items():
+            obs.gauge(f"serve_state_bytes_{kind}",
+                      f"bytes of the state pool's {kind} leaves").set(n)
+
+    def bytes_by_kind(self) -> dict:
+        """Pool bytes by leaf kind: ``kv`` (attention K/V pages), ``ssm``
+        (Mamba-2 state), ``conv`` (Mamba-2 conv tail) and ``other``
+        (GSPN rows, xLSTM state, lengths and positions)."""
+        out = {"kv": 0, "ssm": 0, "conv": 0, "other": 0}
+
+        def add(path, a):
+            name = getattr(path[-1], "key", None)
+            kind = ("kv" if name in KV_LEAVES else
+                    name if name in ("ssm", "conv") else "other")
+            out[kind] += int(a.size) * a.dtype.itemsize
+        jax.tree_util.tree_map_with_path(add, self.caches)
+        return out
 
     @property
     def nbytes(self) -> int:
@@ -237,9 +285,11 @@ class StateCachePool:
 
     # -- state movement -----------------------------------------------------
     def commit(self, slot: int, new_caches):
-        """Scatter a finished batch-1 prefill cache into ``slot``."""
-        self.caches = update_cache_slots(self.cfg, self.caches,
-                                         new_caches, [slot])
+        """Scatter a finished batch-1 prefill cache into ``slot`` (every
+        leaf's slot row, recurrent state and KV alike, so a reused slot
+        keeps nothing of its previous occupant)."""
+        self.caches = commit_slots(self.cfg, self.caches, new_caches,
+                                   jnp.asarray([slot], jnp.int32))
 
     def update(self, caches):
         """Install the post-decode batched caches (all slots at once),

@@ -243,6 +243,11 @@ class ServeEngine:
             self.prefill_chunk = max(align, (prefill_chunk // align) * align)
         else:
             self.prefill_chunk = 0
+        # Where the model masks padding, every prompt is prefilled in
+        # chunks of one length, the last padded: one program per tick
+        # whatever the prompt, and one compile for all lengths.
+        self.pad_chunks = (bool(self.prefill_chunk)
+                           and lm_mod.supports_padded_chunks(cfg))
 
         self.pool = StateCachePool(cfg, batch_size, max_len,
                                    state_dtype=self.state_dtype)
@@ -252,11 +257,14 @@ class ServeEngine:
             lambda p, toks: lm_mod.lm_prefill(p, cfg, toks, max_len,
                                               ctx=self.ctx)[:2])
         self._prefill_chunk_fn = jax.jit(
-            lambda p, toks, caches, off, with_logits: lm_mod.lm_prefill_chunk(
-                p, cfg, toks, caches, off, ctx=self.ctx,
-                with_logits=with_logits),
-            static_argnums=4)
-        self._decode = jax.jit(self._decode_fn)
+            lambda p, toks, caches, off, n_valid, with_logits:
+            lm_mod.lm_prefill_chunk(p, cfg, toks, caches, off, ctx=self.ctx,
+                                    with_logits=with_logits, n_valid=n_valid),
+            static_argnums=5)
+        # the pool's caches are donated: the step writes them in place
+        # (pool.update installs the result), so the pool is held once
+        self._decode = jax.jit(self._decode_fn, donate_argnums=2,
+                               static_argnums=4)
 
     @property
     def on_finish(self):
@@ -310,20 +318,25 @@ class ServeEngine:
         """Clear all scheduling state (fresh pool pages included) but keep
         the compiled functions (benchmark rungs reuse one engine to avoid
         re-jitting)."""
+        # Release the old pool (and the in-flight prefill's cache) before
+        # the new pool is made: a pool can hold most of the device.
+        self.pool = None
+        self._reset_state()
         self.pool = StateCachePool(self.cfg, self.bs, self.max_len,
                                    state_dtype=self.state_dtype)
         self.rng = jax.random.PRNGKey(self._seed)
-        self._reset_state()
 
     # -- jitted decode+sample --------------------------------------------
-    def _decode_fn(self, params, token, caches, rng):
+    def _decode_fn(self, params, token, caches, rng, with_logits=False):
         logits, new_caches = lm_mod.lm_decode_step(params, self.cfg, token,
                                                    caches, ctx=self.ctx)
         # narrow inside the jitted step so the cast fuses with the cache
         # writes instead of costing a separate device pass
         new_caches = narrow_state(new_caches, self.state_dtype)
         nxt = sample_tokens(logits[:, 0], rng, self.temperature, self.top_k)
-        return nxt, new_caches
+        # ``score`` reads the logits; the serving loop's program has no
+        # such output
+        return (nxt, new_caches) + ((logits[:, 0],) if with_logits else ())
 
     # -- request management -------------------------------------------------
     def check_fits(self, req: Request):
@@ -446,7 +459,7 @@ class ServeEngine:
             t_admit = obs.monotonic()
             self._m["admission_order"].append(req.uid)
             obs.event("request.admitted", uid=req.uid, slot=slot)
-            if self.prefill_chunk and len(req.prompt) > self.prefill_chunk:
+            if self._chunked(len(req.prompt)):
                 toks = np.asarray(req.prompt, np.int32)
                 # Prefix/state probe (DESIGN.md §15): a hit hands back the
                 # full boundary-state cache at a chunk-aligned offset k —
@@ -493,13 +506,10 @@ class ServeEngine:
         last = end == len(st["toks"])
         t0 = obs.monotonic()
         with obs.trace("serve.prefill_chunk", uid=st["req"].uid,
-                       index=st["chunks"], offset=off, tokens=end - off):
-            chunk = jnp.asarray(st["toks"][off:end], jnp.int32)[None]
-            # only the final chunk's logits feed sampling; intermediate
-            # chunks skip the vocab-head projection entirely
-            logits, st["cache"] = self._prefill_chunk_fn(
-                self.params, chunk, st["cache"], jnp.asarray(off, jnp.int32),
-                last)
+                       index=st["chunks"], offset=off, tokens=end - off,
+                       resumed=off > 0):
+            logits, st["cache"] = self._run_chunk(st["toks"], off, end,
+                                                  st["cache"])
             # Block so the chunk histogram measures device time, not
             # dispatch: the per-chunk latency is the TTFT predictor's
             # cost model (DESIGN.md §15), and the very next tick would
@@ -523,6 +533,27 @@ class ServeEngine:
                            st["t_submit"], st["t_admit"], st["chunks"],
                            cached=st["cached"])
             self._inflight = None
+
+    def _chunked(self, n: int) -> bool:
+        """Whether a prompt of ``n`` tokens is prefilled in chunks."""
+        return bool(self.prefill_chunk) and (self.pad_chunks
+                                             or n > self.prefill_chunk)
+
+    def _run_chunk(self, toks, off: int, end: int, cache):
+        """Prefill ``toks[off:end]`` into ``cache``; the logits are those
+        of the prompt's last token when ``end`` ends it, else None.  Only
+        the final chunk's logits feed sampling, so intermediate chunks
+        skip the vocab-head projection entirely.  With ``pad_chunks`` the
+        chunk is padded to ``prefill_chunk`` where the cache has the rows
+        for it."""
+        last = end == len(toks)
+        chunk, n_valid = toks[off:end], None
+        if self.pad_chunks and off + self.prefill_chunk <= self.max_len:
+            n_valid = jnp.asarray(end - off, jnp.int32)
+            chunk = np.pad(chunk, (0, self.prefill_chunk - (end - off)))
+        return self._prefill_chunk_fn(
+            self.params, jnp.asarray(chunk, jnp.int32)[None], cache,
+            jnp.asarray(off, jnp.int32), n_valid, last)
 
     def _activate(self, req, slot, first, cache, t_submit, t_admit, chunks,
                   cached: int = 0):
@@ -584,7 +615,15 @@ class ServeEngine:
     def _decode_step(self):
         """One decode step for the whole batch."""
         sm = _serve_metrics()
-        with obs.trace("serve.decode_step", batch=int(self.active.sum())):
+        rows = int(self.active.sum())
+        # KV rows the step's attention reads: every occupied slot's prompt
+        # and the tokens it has streamed so far (the newest is written now)
+        kv_tokens = (sum(len(self.slot_req[s].prompt)
+                         + len(self._slot_res[s].tokens)
+                         for s in np.flatnonzero(self.active))
+                     if obs.enabled() else 0)
+        with obs.trace("serve.decode_step", batch=rows, rows=rows,
+                       kv_tokens=kv_tokens):
             self.rng, sub = jax.random.split(self.rng)
             nxt, new_caches = self._decode(self.params, self.last_token,
                                            self.pool.caches, sub)
@@ -641,6 +680,55 @@ class ServeEngine:
 
     # kept as an alias of the scheduling quantum for older callers
     step = tick
+
+    def score(self, cases) -> list:
+        """Teacher-forced replay through the serving programs: for each
+        ``(prompt, tokens)`` case, the logits (float32, one row per token
+        of ``tokens``) from which each of ``tokens`` was chosen: after the
+        prompt, then after each token but the last.  The prompt runs
+        through the prefill a request takes (chunked and padded as
+        admission would), is committed to a free slot, and the
+        tokens are fed to the batched decode step, one slot per case (the
+        serving step's function, compiled with its logits as an output).
+        Needs an idle engine (``reset`` drops what is in flight) and at
+        most ``batch_size`` cases; frees the slots it took.  Allocates
+        nothing beside the pool but one prompt's cache and one step's
+        logits, so it runs where the pool fills the device."""
+        if not self.idle:
+            raise RuntimeError("score needs an idle engine")
+        if len(cases) > self.bs:
+            raise ValueError(f"{len(cases)} cases for {self.bs} slots")
+        rows, slots = [], []
+        for prompt, _ in cases:
+            toks = np.asarray(prompt, np.int32)
+            if self._chunked(len(toks)):
+                cache = lm_mod.init_lm_cache(self.cfg, 1, self.max_len)
+                for off in range(0, len(toks), self.prefill_chunk):
+                    logits, cache = self._run_chunk(
+                        toks, off, min(off + self.prefill_chunk, len(toks)),
+                        cache)
+            else:
+                logits, cache = self._prefill(self.params,
+                                              jnp.asarray(toks)[None])
+            slot = self.pool.alloc()
+            self.pool.commit(slot, cache)
+            slots.append(slot)
+            rows.append([np.asarray(logits[0, -1], np.float32)])
+        token = np.zeros((self.bs, 1), np.int32)
+        for j in range(max(len(t) for _, t in cases) - 1):
+            for slot, (_, t) in zip(slots, cases):
+                token[slot, 0] = t[min(j, len(t) - 1)]
+            _, new_caches, logits = self._decode(
+                self.params, jnp.asarray(token), self.pool.caches, self.rng,
+                True)
+            self.pool.update(new_caches)
+            got = np.asarray(logits[np.asarray(slots)], np.float32)
+            for i, (_, t) in enumerate(cases):
+                if j < len(t) - 1:
+                    rows[i].append(got[i])
+        for slot in slots:
+            self.pool.free(slot)
+        return [np.stack(r) for r in rows]
 
     def run(self):
         """Run until all submitted requests complete.  Returns results."""

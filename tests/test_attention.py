@@ -102,3 +102,19 @@ def test_decode_attention_masks_beyond_length():
     np.testing.assert_allclose(np.asarray(out_masked), np.asarray(out_ref),
                                rtol=1e-5, atol=1e-5)
     assert not np.allclose(np.asarray(out_full), np.asarray(out_ref))
+
+
+@pytest.mark.parametrize("offset", [0, 5, 40])
+def test_chunk_prefill_blocked_matches_dense(offset):
+    """Over a cache longer than the key block, chunked-prefill attention
+    sweeps key blocks with an online softmax; it equals the dense form."""
+    from repro.models.attention import chunk_prefill_attention
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (2, 12, 4, 8))
+    kc = jax.random.normal(ks[1], (2, 64, 2, 8))
+    vc = jax.random.normal(ks[2], (2, 64, 2, 8))
+    off = jnp.asarray(offset, jnp.int32)
+    dense = chunk_prefill_attention(q, kc, vc, off, block_k=64)
+    blocked = chunk_prefill_attention(q, kc, vc, off, block_k=16)
+    np.testing.assert_allclose(np.asarray(blocked), np.asarray(dense),
+                               atol=1e-5, rtol=1e-5)

@@ -85,8 +85,9 @@ def test_cell_matrix_covers_assignment():
     from repro.launch.dryrun import cell_matrix
     cells = cell_matrix()
     lm_cells = [c for c in cells if c[0] == "lm"]
-    # 11 archs (10 assigned + 1 beyond-paper) × 4 shapes
-    assert len(lm_cells) == 44
+    # 12 archs (10 assigned + 2 beyond: the GSPN LM, the granite hybrid)
+    # × 4 shapes
+    assert len(lm_cells) == 48
     skips = [c for c in lm_cells if c[3] is not None]
     assert len(skips) == 8            # long_500k × full-attention archs
     assert all(c[2] == "long_500k" for c in skips)

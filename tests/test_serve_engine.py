@@ -102,8 +102,13 @@ def test_engine_chunked_equals_one_shot_tokens():
 def test_chunk_support_matrix():
     assert supports_chunked_prefill(_gspn_cfg())
     assert prefill_chunk_alignment(_gspn_cfg()) == 8
-    # mamba has no incremental prefill; row_width=0 defeats a fixed fold
-    assert not supports_chunked_prefill(_gspn_cfg(unit=(("mamba", 1),)))
+    # Mamba-2 resumes from its state and conv tail, and aligns chunks to
+    # its scan chunk; xLSTM has no incremental prefill; row_width=0
+    # defeats a fixed fold
+    assert supports_chunked_prefill(_gspn_cfg(unit=(("mamba", 1),)))
+    assert prefill_chunk_alignment(
+        _gspn_cfg(unit=(("mamba", 1),), gla_chunk=16)) == 16
+    assert not supports_chunked_prefill(_gspn_cfg(unit=(("mlstm", 1),)))
     assert not supports_chunked_prefill(_gspn_cfg(gspn_row_width=0))
     eng = ServeEngine(init_lm(jax.random.PRNGKey(0), _gspn_cfg()),
                       _gspn_cfg(), batch_size=1, max_len=64,

@@ -21,7 +21,10 @@ fused pair, f32 and bf16 streams, and assert a Mosaic kernel
   forward and adjoint in f32: train_224 (batch 32, G = 64) at W = H ∈
   {56, 28, 14, 7} and infer_1024 (batch 8, G = 16) at W = H ∈ {256, 128,
   64, 32} — the plan the heuristic picks, which is the plan they run
-  (every enumerated plan there would add minutes of compiles).
+  (every enumerated plan there would add minutes of compiles);
+* granite-4.0-h-micro's serving programs at the hybrid cell's pool (no
+  kernel: a memory check that the decode step and a prompt chunk fit
+  one chip beside 32 slots of SSM state and KV).
 
 The topology is described inside a module fixture, never while a module
 is imported: only the worker that runs this file loads the TPU library.
@@ -193,3 +196,52 @@ def test_lm_mixer_at_full_width_compiles(one_chip):
     text = _compiled_text(
         lambda p, x: G.apply_gspn_seq_mixer(p, x, cfg), [params, x])
     assert text.count("tpu_custom_call") >= 2       # T→B + within-row
+
+
+# granite4h.serve_doc's engine on one chip: 32 slots of 16384 + 1024 rows.
+HYBRID_SLOTS, HYBRID_ROWS, HYBRID_CHUNK = 32, 17408, 2048
+HBM_BYTES = 15.75e9                       # what the v5e compiler allots
+
+
+def test_hybrid_serving_programs_fit_one_chip(one_chip):
+    """granite-4.0-h-micro's decode step over the donated 32-slot pool (as
+    the engine serves it, and with its logits as an output, as
+    ``ServeEngine.score`` replays it), and a padded prompt chunk beside
+    that pool, fit one v5e: the decodes' compiled peaks, and the chunk's
+    peak plus the resident pool, stay under the chip's HBM (the in-place
+    stage walk and the blocked chunk attention are what make them fit; a
+    fresh-buffer walk peaked at 15.9 GB)."""
+    from repro.configs.base import with_precision
+    from repro.configs.granite_4_0_h_micro import full
+    from repro.models import lm
+    from repro.serve.engine import sample_tokens
+    cfg = with_precision(full(), "bf16")
+
+    def shapes(fn):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), jax.eval_shape(fn))
+
+    params = shapes(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg))
+    pool = shapes(lambda: lm.init_lm_cache(cfg, HYBRID_SLOTS, HYBRID_ROWS))
+    one = shapes(lambda: lm.init_lm_cache(cfg, 1, HYBRID_ROWS))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(p, token, caches, rng, with_logits):
+        logits, new = lm.lm_decode_step(p, cfg, token, caches)
+        nxt = sample_tokens(logits[:, 0], rng, 0.0, 0)
+        return (nxt, new) + ((logits[:, 0],) if with_logits else ())
+
+    decs = [jax.jit(decode, donate_argnums=2, static_argnums=4).lower(
+        params, arg((HYBRID_SLOTS, 1), jnp.int32), pool,
+        arg((2,), jnp.uint32), flag).compile().memory_analysis()
+        for flag in (False, True)]
+    chunk = jax.jit(lambda p, t, c, o, n: lm.lm_prefill_chunk(
+        p, cfg, t, c, o, with_logits=True, n_valid=n)).lower(
+        params, arg((1, HYBRID_CHUNK), jnp.int32), one,
+        arg((), jnp.int32), arg((), jnp.int32)).compile().memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    for dec in decs:
+        assert dec.peak_memory_in_bytes <= HBM_BYTES
+    assert chunk.peak_memory_in_bytes + pool_bytes <= HBM_BYTES
