@@ -28,6 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+from repro.kernels import chunk_attention
 from repro.models.layers import DTypePolicy, DEFAULT_POLICY, dense_init
 
 NEG_INF = -1e30
@@ -210,13 +212,38 @@ def chunk_prefill_attention(q, k_cache, v_cache, q_offset,
     written.  Key j is visible iff j <= q_offset + i, so the result equals
     one-shot causal prefill restricted to these T query rows.
 
-    Over the whole padded cache, like ``decode_attention`` — the traced
-    offset cannot go through ``chunked_attention`` (``q_offset`` is a
-    nondiff_argnum of the flash vjp, so it would recompile per offset).
-    A cache longer than ``block_k`` (and a multiple of it) is swept one
-    block of keys at a time with an online softmax (``_fwd_blocks``), so
-    the logits held at once are T x block_k, not T x S: a 2048-token chunk
-    over a 17k-row cache would otherwise hold 4.6 GB of f32 logits."""
+    On a TPU, a shape the fused kernel takes (``kernels/chunk_attention``:
+    one launch, the logits kept in VMEM, only the key blocks the chunk can
+    see) runs it; everywhere else, and for any other shape, the XLA sweep
+    ``_chunk_sweep`` does.  The choice is made per platform at lowering
+    (``lax.platform_dependent``) and per shape at trace time, which the
+    registry counters ``chunk_attention_{pallas,xla}_total`` count."""
+    t, hq, d = q.shape[1:]
+    s, hkv = k_cache.shape[1:3]
+    sweep = functools.partial(_chunk_sweep, block_k=block_k)
+    if chunk_attention.tiles(t, s, d, hq // hkv) is None:
+        obs.counter("chunk_attention_xla_total",
+                    "chunk attentions traced with the XLA sweep alone "
+                    "(a shape the kernel does not take)").inc()
+        return sweep(q, k_cache, v_cache, q_offset)
+    obs.counter("chunk_attention_pallas_total",
+                "chunk attentions traced with the fused kernel as their "
+                "TPU path").inc()
+    return jax.lax.platform_dependent(
+        q, k_cache, v_cache, q_offset,
+        tpu=chunk_attention.chunk_attention, default=sweep)
+
+
+def _chunk_sweep(q, k_cache, v_cache, q_offset, block_k: int = 512):
+    """The XLA form of :func:`chunk_prefill_attention`, its reference and
+    its path off the TPU.  Over the whole padded cache, like
+    ``decode_attention`` — the traced offset cannot go through
+    ``chunked_attention`` (``q_offset`` is a nondiff_argnum of the flash
+    vjp, so it would recompile per offset).  A cache longer than
+    ``block_k`` (and a multiple of it) is swept one block of keys at a time
+    with an online softmax (``_fwd_blocks``), so the logits held at once
+    are T x block_k, not T x S: a 2048-token chunk over a 17k-row cache
+    would otherwise hold 4.6 GB of f32 logits."""
     b, t, hq, d = q.shape
     s = k_cache.shape[1]
     hkv = k_cache.shape[2]

@@ -20,8 +20,10 @@ sys.path.insert(0, str(BENCH))
 
 from refs import granite_hybrid as ref  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.configs.base import get_arch, with_precision  # noqa: E402
-from repro.models import lm  # noqa: E402
+from repro.kernels import chunk_attention as CA  # noqa: E402
+from repro.models import attention, lm  # noqa: E402
 from repro.models import ssm  # noqa: E402
 from repro.serve.cache import StateCachePool, narrow_state  # noqa: E402
 from repro.serve.engine import Request, ServeEngine  # noqa: E402
@@ -115,14 +117,43 @@ def test_one_shot_prefill_then_decode_matches_reference(setup):
     assert _gap(got, want[:, :-1]) <= F32_ATOL
 
 
-@pytest.mark.parametrize("chunk", [2, 5, 8, 13],
-                         ids=["under-conv-tail", "ragged", "ssd-chunk",
-                              "ragged-long"])
-def test_chunked_prefill_then_decode_matches_reference(setup, chunk):
+def _cases(chunks, ids):
+    """Each chunk length on the XLA sweep (the ids as they were) and on the
+    fused kernel (``-pallas``)."""
+    return ([pytest.param(c, "xla", id=i) for c, i in zip(chunks, ids)]
+            + [pytest.param(c, "pallas", id=f"{i}-pallas")
+               for c, i in zip(chunks, ids)])
+
+
+def _chunk_path(path, monkeypatch):
+    """Run the chunks' attention on ``path``.  Off the TPU the dispatch
+    takes the XLA sweep; for ``pallas`` the test puts the fused kernel, in
+    interpret mode, in the sweep's place, as a TPU would run it.  Returns a
+    check that the kernel was the dispatch's choice for these shapes."""
+    if path == "pallas":
+        monkeypatch.setattr(
+            attention, "_chunk_sweep",
+            lambda q, k, v, off, block_k: CA.chunk_attention(
+                q, k, v, off, interpret=True))
+    before = _count("chunk_attention_pallas_total")
+    return lambda: _count("chunk_attention_pallas_total") > before
+
+
+def _count(name):
+    return getattr(obs.REGISTRY.get(name), "value", 0)
+
+
+@pytest.mark.parametrize("chunk,path", _cases(
+    [2, 5, 8, 13], ["under-conv-tail", "ragged", "ssd-chunk",
+                    "ragged-long"]))
+def test_chunked_prefill_then_decode_matches_reference(setup, chunk, path,
+                                                       monkeypatch):
     """Chunks shorter than the conv tail (conv_k - 1 = 3), chunks that do
     not divide the prompt (a ragged last chunk), and chunks that straddle
-    the SSD chunk (8): every chunk resumes SSM state and conv tail."""
+    the SSD chunk (8): every chunk resumes SSM state and conv tail, with
+    its attention on the XLA sweep or the fused kernel."""
     cfg, params, toks, want = setup
+    took_kernel = _chunk_path(path, monkeypatch)
     with jax.default_matmul_precision("highest"):
         caches = lm.init_lm_cache(cfg, 2, 64)
         out, off = [], 0
@@ -134,18 +165,22 @@ def test_chunked_prefill_then_decode_matches_reference(setup, chunk):
             out.append(logits)
             off = end
         got = _decode_rest(params, cfg, toks, caches, out)
+    assert took_kernel()
     assert _gap(got, want[:, :-1]) <= F32_ATOL
 
 
-@pytest.mark.parametrize("chunk", [2, 8, 13],
-                         ids=["under-conv-tail", "ssd-chunk", "ragged-long"])
-def test_padded_chunks_then_decode_match_reference(setup, chunk):
+@pytest.mark.parametrize("chunk,path", _cases(
+    [2, 8, 13], ["under-conv-tail", "ssd-chunk", "ragged-long"]))
+def test_padded_chunks_then_decode_match_reference(setup, chunk, path,
+                                                   monkeypatch):
     """Every chunk padded to ``chunk`` rows with only the prompt's tokens
     counted (``n_valid``), as the engine runs a ragged last chunk: the
     padding leaves SSM state, conv tail and KV length as the tokens left
-    them, and the logits are those of the last token."""
+    them, and the logits are those of the last token, with the chunks'
+    attention on the XLA sweep or the fused kernel."""
     cfg, params, toks, want = setup
     assert lm.supports_padded_chunks(cfg)
+    took_kernel = _chunk_path(path, monkeypatch)
     with jax.default_matmul_precision("highest"):
         caches = lm.init_lm_cache(cfg, 2, 64)
         off = 0
@@ -158,7 +193,27 @@ def test_padded_chunks_then_decode_match_reference(setup, chunk):
             off += n
         assert logits.shape == (2, 1, cfg.vocab)
         got = _decode_rest(params, cfg, toks, caches, [logits])
+    assert took_kernel()
     assert _gap(got, want[:, PROMPT - 1:-1]) <= F32_ATOL
+
+
+@pytest.mark.parametrize("arch,kernels", [("granite-4.0-h-micro", 4),
+                                          ("qwen2-1.5b-gspn", 0)])
+def test_chunk_program_counts_its_kernel_attentions(arch, kernels):
+    """Tracing a 2048-token chunk over a 17,408-row cache (serve_doc's
+    program) engages the fused kernel once per attention layer, and the
+    GSPN model (serve_chat's, no attention layer) engages no chunk
+    attention at all."""
+    cfg = with_precision(get_arch(arch).full(), "bf16")
+    params = jax.eval_shape(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg))
+    caches = jax.eval_shape(lambda: lm.init_lm_cache(cfg, 1, 17408))
+    before = (_count("chunk_attention_pallas_total"),
+              _count("chunk_attention_xla_total"))
+    jax.eval_shape(lambda p, t, c, o: lm.lm_prefill_chunk(p, cfg, t, c, o),
+                   params, jax.ShapeDtypeStruct((1, 2048), jnp.int32),
+                   caches, jax.ShapeDtypeStruct((), jnp.int32))
+    assert _count("chunk_attention_pallas_total") == before[0] + kernels
+    assert _count("chunk_attention_xla_total") == before[1]
 
 
 def test_a_chunk_that_drops_the_ssm_state_is_caught(setup):

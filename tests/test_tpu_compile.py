@@ -22,9 +22,13 @@ fused pair, f32 and bf16 streams, and assert a Mosaic kernel
   {56, 28, 14, 7} and infer_1024 (batch 8, G = 16) at W = H ∈ {256, 128,
   64, 32} — the plan the heuristic picks, which is the plan they run
   (every enumerated plan there would add minutes of compiles);
-* granite-4.0-h-micro's serving programs at the hybrid cell's pool (no
-  kernel: a memory check that the decode step and a prompt chunk fit
-  one chip beside 32 slots of SSM state and KV).
+* the fused chunked-prefill attention kernel at the hybrid cell's shape
+  (a 2048-token chunk over a 17,408-row bf16 cache, 32 query heads on 8
+  KV heads of dim 64), as the model's dispatch selects it for a TPU;
+* granite-4.0-h-micro's serving programs at the hybrid cell's pool (a
+  memory check that the decode step and a prompt chunk, with the chunk
+  attention kernel in it, fit one chip beside 32 slots of SSM state and
+  KV).
 
 The topology is described inside a module fixture, never while a module
 is imported: only the worker that runs this file loads the TPU library.
@@ -203,14 +207,33 @@ HYBRID_SLOTS, HYBRID_ROWS, HYBRID_CHUNK = 32, 17408, 2048
 HBM_BYTES = 15.75e9                       # what the v5e compiler allots
 
 
+def test_chunk_attention_kernel_compiles_at_the_hybrid_cell_shape(one_chip):
+    """``chunk_prefill_attention`` lowered for a TPU at serve_doc's chunk
+    shape takes the fused kernel: a Mosaic kernel and no key-block loop in
+    the compiled program."""
+    from repro.models.attention import chunk_prefill_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compiled_text(chunk_prefill_attention, [
+        arg((1, HYBRID_CHUNK, 32, 64), jnp.bfloat16),
+        arg((1, HYBRID_ROWS, 8, 64), jnp.bfloat16),
+        arg((1, HYBRID_ROWS, 8, 64), jnp.bfloat16), arg((), jnp.int32)])
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text
+
+
 def test_hybrid_serving_programs_fit_one_chip(one_chip):
     """granite-4.0-h-micro's decode step over the donated 32-slot pool (as
     the engine serves it, and with its logits as an output, as
     ``ServeEngine.score`` replays it), and a padded prompt chunk beside
     that pool, fit one v5e: the decodes' compiled peaks, and the chunk's
     peak plus the resident pool, stay under the chip's HBM (the in-place
-    stage walk and the blocked chunk attention are what make them fit; a
-    fresh-buffer walk peaked at 15.9 GB)."""
+    stage walk and the chunk attention that never holds T x S logits are
+    what make them fit; a fresh-buffer walk peaked at 15.9 GB).  The
+    chunk program carries the fused attention kernel, once per attention
+    layer."""
     from repro.configs.base import with_precision
     from repro.configs.granite_4_0_h_micro import full
     from repro.models import lm
@@ -237,10 +260,12 @@ def test_hybrid_serving_programs_fit_one_chip(one_chip):
         params, arg((HYBRID_SLOTS, 1), jnp.int32), pool,
         arg((2,), jnp.uint32), flag).compile().memory_analysis()
         for flag in (False, True)]
-    chunk = jax.jit(lambda p, t, c, o, n: lm.lm_prefill_chunk(
+    chunk_prog = jax.jit(lambda p, t, c, o, n: lm.lm_prefill_chunk(
         p, cfg, t, c, o, with_logits=True, n_valid=n)).lower(
         params, arg((1, HYBRID_CHUNK), jnp.int32), one,
-        arg((), jnp.int32), arg((), jnp.int32)).compile().memory_analysis()
+        arg((), jnp.int32), arg((), jnp.int32)).compile()
+    assert chunk_prog.as_text().count("tpu_custom_call") == 4
+    chunk = chunk_prog.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
     for dec in decs:
         assert dec.peak_memory_in_bytes <= HBM_BYTES
